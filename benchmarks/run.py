@@ -24,6 +24,8 @@ def main() -> None:
                     help="paper-scale datasets (slow on 1 CPU core)")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     suites = [args.only] if args.only else SUITES
     failures = []

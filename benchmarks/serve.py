@@ -2,9 +2,9 @@
 
 Four CI-facing contracts (BENCH_serve.json, repo root):
 
-1. **Parity** (hard gate) — served logits bitwise-equal to the offline
-   eval forward for a probe set, with the hot-feature cache enabled and
-   refreshing mid-run. Exact 0/1.
+1. **Parity** (hard gate) — served logits bitwise-equal to the compiled
+   offline eval program at the same padded rung for a probe set, with the
+   hot-feature cache enabled and refreshing mid-run. Exact 0/1.
 2. **Retraces** (hard gate) — steady-state serving compiles nothing after
    :meth:`GNNServer.warmup`; the engine trace log's ``infer`` count must
    not move across the whole bench. Exact 0.
@@ -25,7 +25,7 @@ from benchmarks.common import Bench, setup
 from repro.core.distributed import infer_trace_count
 from repro.features import FeatureStore
 from repro.serve import GNNServer
-from repro.train.budget import ShapeBudget
+from repro.train.budget import ShapeBudget, next_bucket
 
 SPEEDUP_GATE_X = 2.0
 QPS_FRACTIONS = (0.25, 0.5, 0.8)
@@ -126,18 +126,22 @@ def run(quick: bool = True) -> Bench:
 
     # ---- hard gates: parity + compile-once -----------------------------
     import jax.numpy as jnp
-    from repro.graph.sampler import sample_tree_block
-    from repro.models.gnn import gnn_forward
+    from repro.core import get_compiled_inference, plan_inference
     probe = np.unique(rng.integers(0, n, 32))
     got = dyn.predict(probe.tolist())
-    blk = sample_tree_block(env["ds"].graph, probe, cfg.num_layers,
-                            cfg.fanout, seed=999)
-    feats = [jnp.asarray(store.take_global(ids)) for ids in blk.hops]
-    ref = np.asarray(gnn_forward(params, cfg, feats))
+    # read before the reference below compiles a program of its own
+    retraces = infer_trace_count() - traces_baseline
+    # the offline eval program: no cache, features straight from the store,
+    # at the rung the one-micro-batch probe was served at
+    plan = plan_inference(env["ds"].graph, probe, cfg.num_layers, cfg.fanout,
+                          sample_seed=999, batch_pad=next_bucket(
+                              probe.size, dyn.budget.min_batch_pad))
+    fetched = jnp.asarray(store.take_global(plan.fetch_ids))
+    ref = np.asarray(get_compiled_inference(cfg)(
+        params, jnp.zeros((0, store.feature_dim), fetched.dtype), fetched,
+        *[jnp.asarray(h) for h in plan.hop_idx]))[:probe.size]
     b.emit("parity", "bitwise_equal", int(np.array_equal(got, ref)))
-
-    b.emit("retraces", "after_warmup",
-           infer_trace_count() - traces_baseline)
+    b.emit("retraces", "after_warmup", retraces)
     b.emit("retraces", "cache_installs", dyn.stats()["cache_installs"])
 
     b.save_csv()

@@ -22,6 +22,7 @@ import argparse
 
 import jax
 
+from repro.compile_cache import use_compile_cache
 from repro.core import distributed as engine
 from repro.graph import make_dataset
 from repro.graph.partition import community_partition, shard_features
@@ -52,6 +53,7 @@ def main() -> None:
                     help="K-stacked scan dispatch (amortizes dispatch "
                          "overhead when device iterations are tiny)")
     args = ap.parse_args()
+    use_compile_cache()
     P = PRESETS[args.preset]
 
     ds = make_dataset("products", scale=P["scale"], seed=0,

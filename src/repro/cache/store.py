@@ -92,7 +92,7 @@ class CacheStore:
     """
 
     def __init__(self, num_shards: int, feature_dim: int, c_max: int = 0,
-                 dtype=np.float32):
+                 dtype=np.float32, sharding=None):
         self.num_shards = int(num_shards)
         self.feature_dim = int(feature_dim)
         self.dtype = np.dtype(dtype)
@@ -103,6 +103,7 @@ class CacheStore:
         self._host = np.zeros((self.num_shards, self.c_max, self.feature_dim),
                               self.dtype)
         self._device = None          # uploaded lazily, invalidated on install
+        self.sharding = sharding     # per-shard placement under a mesh
         self.installs = 0
         self.repads = 0              # c_max re-buckets (shape changes)
 
@@ -110,11 +111,12 @@ class CacheStore:
 
     @property
     def device_table(self):
-        """(N, c_max, d) jnp array, cached across calls until an install."""
+        """(N, c_max, d) device array, cached across calls until an
+        install; placed with ``sharding`` when one was given."""
         if self._device is None:
-            import jax.numpy as jnp
+            from repro.core.distributed import _as_device
             with _obs_span("cache.upload", bytes=int(self._host.nbytes)):
-                self._device = jnp.asarray(self._host)
+                self._device = _as_device(self._host, self.sharding)
             _obs_metrics.inc("cache.upload_bytes", int(self._host.nbytes))
         return self._device
 

@@ -63,22 +63,12 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.extend import core as jex_core
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.gnn.models import GNNConfig, gnn_forward, gnn_loss
 from repro.obs import metrics as _obs_metrics
 from repro.obs import trace as _obs_trace
-
-
-def _shard_map(body, mesh, in_specs, out_specs):
-    """Version-compatible shard_map: jax.shard_map(check_vma=...) moved from
-    jax.experimental.shard_map.shard_map(check_rep=...)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
 
 
 def tree_add(a, b):
@@ -473,10 +463,20 @@ def resolve_fold_returns(plan, fold_returns: Optional[bool] = None) -> bool:
     return plan.num_steps * plan.r_max <= FOLD_RETURNS_MAX_TR
 
 
-def _as_device(x):
-    """Upload only host-side leaves: device-resident arrays pass through
-    untouched (no per-leaf re-wrap on the hot path)."""
-    return x if isinstance(x, jax.Array) else jnp.asarray(x)
+def shard_sharding(mesh: Optional[Mesh], axis: str = "data"):
+    """Placement of an array whose leading axis is the shard axis (feature
+    table, cache table, plan device args): under a mesh each device holds
+    its own shard; without one, ``None`` (the default device)."""
+    return None if mesh is None else NamedSharding(mesh, P(axis))
+
+
+def _as_device(x, sharding=None):
+    """Upload only host-side leaves (to ``sharding`` when given):
+    device-resident arrays pass through untouched (no per-leaf re-wrap on
+    the hot path)."""
+    if isinstance(x, jax.Array):
+        return x
+    return jnp.asarray(x) if sharding is None else jax.device_put(x, sharding)
 
 
 # Host comm boundary hook (repro.resilience). In a multi-host deployment
@@ -558,22 +558,23 @@ def comm_fault_point(plan) -> None:
             "exchange fan-out cannot be staged", peer=peer)
 
 
-# (num_shards, feature_dim, dtype) -> (N, 0, d) device zeros. Cache-off
-# iterations all share one zero-width cache table instead of allocating a
-# fresh one per call (part of the per-iteration host overhead PR 5 removes).
+# (num_shards, feature_dim, dtype, sharding) -> (N, 0, d) device zeros.
+# Cache-off iterations all share one zero-width cache table instead of
+# allocating a fresh one per call (per-iteration host overhead).
 _EMPTY_CACHE: dict = {}
 
 
-def empty_cache_table(num_shards: int, feature_dim: int, dtype=np.float32):
-    key = (int(num_shards), int(feature_dim), np.dtype(dtype).str)
+def empty_cache_table(num_shards: int, feature_dim: int, dtype=np.float32,
+                      sharding=None):
+    key = (int(num_shards), int(feature_dim), np.dtype(dtype).str, sharding)
     tab = _EMPTY_CACHE.get(key)
     if tab is None:
-        tab = jnp.zeros((key[0], 0, key[1]), key[2])
+        tab = _as_device(np.zeros((key[0], 0, key[1]), key[2]), sharding)
         _EMPTY_CACHE[key] = tab
     return tab
 
 
-def prepare_iteration_args(table_global, plan, cache=None):
+def prepare_iteration_args(table_global, plan, cache=None, sharding=None):
     """Shared argument prep for :func:`run_iteration` /
     :func:`run_train_step`: validates the cache against the plan and
     returns device-ready ``(table, cache, dev, denom)``.
@@ -582,6 +583,8 @@ def prepare_iteration_args(table_global, plan, cache=None):
     whose device args were pre-committed by the pipeline uploader
     (``plan.committed``, see repro.train.pipeline) skips the conversion
     walk entirely — the upload already happened off the critical path.
+    Host-side leaves are placed with ``sharding`` (:func:`shard_sharding`
+    of the mesh the step runs on).
 
     Streamed plans (repro.features): no resident table exists —
     ``table_global=None`` is replaced by the shared zero-width placeholder
@@ -593,17 +596,18 @@ def prepare_iteration_args(table_global, plan, cache=None):
                              "plans (tiered FeatureStore)")
         fl = plan.feat_local
         table_global = empty_cache_table(plan.num_shards, fl.shape[-1],
-                                         fl.dtype)
-    table_global = _as_device(table_global)
+                                         fl.dtype, sharding)
+    table_global = _as_device(table_global, sharding)
     if cache is None:
         if plan.c_max:
             raise ValueError(
                 f"plan was built against a cache (c_max={plan.c_max}) "
                 "but no cache table was passed")
         cache = empty_cache_table(table_global.shape[0],
-                                  table_global.shape[-1], table_global.dtype)
+                                  table_global.shape[-1], table_global.dtype,
+                                  sharding)
     else:
-        cache = _as_device(cache)
+        cache = _as_device(cache, sharding)
         if int(cache.shape[1]) != int(plan.c_max):
             raise ValueError(
                 f"cache table height {cache.shape[1]} != plan c_max "
@@ -612,7 +616,8 @@ def prepare_iteration_args(table_global, plan, cache=None):
     if committed is not None:
         dev, denom = committed["dev"], committed["denom"]
     else:
-        dev = jax.tree.map(_as_device, plan.device_args())
+        dev = jax.tree.map(lambda x: _as_device(x, sharding),
+                           plan.device_args())
         denom = jnp.asarray(float(plan.global_batch), jnp.float32)
     return table_global, cache, dev, denom
 
@@ -636,7 +641,7 @@ def run_iteration(params, table_global, plan, cfg: GNNConfig,
     calls with plans of the same device shapes reuse one compiled program.
     """
     table_global, cache, dev, denom = prepare_iteration_args(
-        table_global, plan, cache)
+        table_global, plan, cache, shard_sharding(mesh))
     fn = get_compiled_iteration(cfg, plan.pregather, mesh=mesh,
                                 fold_returns=resolve_fold_returns(
                                     plan, fold_returns),
@@ -657,7 +662,7 @@ def run_train_step(params, opt_state, table_global, plan, cfg: GNNConfig,
     ``float(loss)`` only when you actually need the value.
     """
     table_global, cache, dev, denom = prepare_iteration_args(
-        table_global, plan, cache)
+        table_global, plan, cache, shard_sharding(mesh))
     fn = get_compiled_train_step(cfg, plan.pregather, optimizer, mesh=mesh,
                                  fold_returns=resolve_fold_returns(
                                      plan, fold_returns),
@@ -707,8 +712,9 @@ def _grads_callable(cfg: GNNConfig, pregather: bool, fold_returns: bool,
                                            comm)
         return grads, loss
 
-    return _shard_map(body, mesh, (P(), P(axis), P(axis), P(axis), P()),
-                      (P(), P()))
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(), P(axis), P(axis), P(axis), P()),
+                         out_specs=(P(), P()), check_vma=False)
 
 
 def _streamed_shard(params, cache, dev, cfg: GNNConfig, denom,
@@ -799,12 +805,9 @@ def _count_collectives(jaxpr, mult: int, counts: dict) -> None:
 
 
 def _subjaxprs(v):
-    from jax.extend import core as jex_core  # jax.core aliases, 0.4-compat
-    ClosedJaxpr = getattr(jex_core, "ClosedJaxpr", None) or jax.core.ClosedJaxpr
-    Jaxpr = getattr(jex_core, "Jaxpr", None) or jax.core.Jaxpr
-    if isinstance(v, ClosedJaxpr):
+    if isinstance(v, jex_core.ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, Jaxpr):
+    elif isinstance(v, jex_core.Jaxpr):
         yield v
     elif isinstance(v, (list, tuple)):
         for w in v:

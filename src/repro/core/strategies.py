@@ -531,12 +531,14 @@ def plan_inference(graph: CSRGraph, nodes: np.ndarray, num_layers: int,
                    pad_vertex: int = 0) -> InferencePlan:
     """Plan one serving micro-batch: sample, dedup, translate.
 
-    Bit-parity contract with the offline eval path (repro.train's
-    ``Trainer.evaluate``): the stateless sampler makes each root's tree a
-    pure function of ``(root, sample_seed)`` — independent of batch
-    composition — and the forward is row-wise per root, so the logits of a
-    served vertex equal the offline ``take_global``-path forward's exactly,
-    no matter how the micro-batcher packed it. Padding roots (``pad_vertex``
+    Bit-parity contract with the offline eval program: the stateless
+    sampler makes each root's tree a pure function of ``(root,
+    sample_seed)`` — independent of batch composition — and the forward is
+    row-wise per root, so the logits of a served vertex equal those of the
+    compiled inference program run on ``take_global`` features at the same
+    ``batch_pad`` exactly, whichever micro-batch carried it. (Programs
+    compiled for different rungs, and the eager ``Trainer.evaluate``
+    forward, agree up to float32 rounding.) Padding roots (``pad_vertex``
     trees filling the rung) are computed and discarded.
 
     ``cache_index`` splits unique ids into hot rows (already device-resident

@@ -7,14 +7,19 @@ These are the compute hot-spots of LeapGNN's data path (DESIGN.md §2):
 * ``gather_agg``   — fused neighbor gather + segment reduction over the
   fixed-fanout axis, replacing DGL's SpMM. On GPU this is a scatter-based
   sparse kernel; the TPU-native re-expression uses the *regular* (n, f)
-  neighbor-index matrix: each grid step DMAs one feature row (sublane-
-  aligned) from the table and accumulates into the output block resident in
-  VMEM — no atomics (TPU has none), no scatter, MXU-friendly d-tiles.
+  neighbor-index matrix and accumulates each fanout position into an
+  output block resident in VMEM — no atomics (TPU has none), no scatter.
 
-Both use ``PrefetchScalarGridSpec``: the index matrix is scalar-prefetched
-into SMEM so the BlockSpec ``index_map`` can steer each grid step's DMA to
-the dynamically-selected table row — the canonical Pallas-TPU gather
-pattern. Feature dim is tiled at 128 lanes (MXU/VPU width).
+Both kernels move rows with explicit DMAs. The table stays in HBM
+(``memory_space=pl.ANY``) viewed as ``(R, 1, w)`` rows of ``w`` 32-bit
+words, ``w`` a multiple of the 128-lane tile: Mosaic refuses a DMA of one
+row of an ``(R, d)`` array (a one-sublane slice of an (8, 128) tile) and
+of a row narrower than a lane tile, but copies a whole ``(1, w)`` row of
+the 3-D view. Each grid step takes :data:`ROWS_PER_STEP` indices as an
+SMEM block, starts one row DMA per index into its VMEM output block, and
+waits for all of them; the output block is then written back by the
+pipeline. Index blocks are per step, so the index count is not bounded by
+SMEM (1 MiB on v5e), as it is for a scalar-prefetched index array.
 """
 from __future__ import annotations
 
@@ -25,76 +30,100 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LANE = 128  # TPU lane width; feature tiles are multiples of this
+LANE = 128  # TPU lane width; rows are padded to whole lane tiles
+
+# Rows DMA'd per grid step. It is also the SMEM index block, which must
+# match the 1024-element tiling XLA gives a 1-D int32 array.
+ROWS_PER_STEP = 1024
 
 
-def _dblk(d: int) -> int:
-    """Feature-dim block for a dim that needs no split: one lane tile when
-    the dim divides evenly, whole dim when it fits inside one tile."""
-    return LANE if d % LANE == 0 else d
+def _as_words(table: jnp.ndarray) -> jnp.ndarray:
+    """(R, d) table -> (R, 1, w) rows of 32-bit words, w % LANE == 0.
+    Rows of 8-/16-bit dtypes are packed into uint32 words."""
+    R, d = table.shape
+    per_word = 4 // table.dtype.itemsize
+    w = -(-d // (LANE * per_word)) * LANE
+    t = jnp.pad(table, ((0, 0), (0, w * per_word - d)))
+    if per_word > 1:
+        t = jax.lax.bitcast_convert_type(t.reshape(R, w, per_word),
+                                         jnp.uint32)
+    return t.reshape(R, 1, w)
 
 
-def _dim_splits(d: int) -> list[tuple[int, int, int]]:
-    """Partition the feature dim into lane-tileable column ranges.
+def _from_words(words: jnp.ndarray, n: int, dtype, d: int) -> jnp.ndarray:
+    """Inverse of :func:`_as_words` for the first ``n`` gathered rows."""
+    x = words[:n, 0]
+    if x.dtype != dtype:
+        x = jax.lax.bitcast_convert_type(x, dtype).reshape(n, -1)
+    return x[:, :d]
 
-    Returns ``[(offset, width, block)]``. A dim that divides by LANE (or
-    fits in one tile) is a single range; d > LANE with a remainder tiles
-    the first ``d // LANE * LANE`` lanes at LANE and the tail as one
-    sub-lane block — instead of the old whole-dim fallback, which put the
-    entire (possibly multi-thousand-column) row in one VMEM block and lost
-    lane alignment on all of it.
-    """
-    if d % LANE == 0 or d < LANE:
-        return [(0, d, _dblk(d))]
-    main = d // LANE * LANE
-    return [(0, main, LANE), (main, d - main, d - main)]
+
+def _dma_rows(idx_ref, table_hbm, dst_ref, sem, count) -> None:
+    """Copy ``table_hbm[idx_ref[r]]`` to ``dst_ref[r]`` for r < count:
+    start every row DMA, then wait for all of them on one semaphore."""
+    def copy(r, row):
+        return pltpu.make_async_copy(table_hbm.at[pl.ds(row, 1)],
+                                     dst_ref.at[pl.ds(r, 1)], sem)
+
+    def start(r, carry):
+        copy(r, idx_ref[r]).start()
+        return carry
+
+    def wait(r, carry):
+        copy(r, 0).wait()
+        return carry
+
+    jax.lax.fori_loop(0, count, start, 0)
+    jax.lax.fori_loop(0, count, wait, 0)
+
+
+def _padded(idx: jnp.ndarray) -> jnp.ndarray:
+    n = idx.shape[-1]
+    pad = -n % ROWS_PER_STEP
+    return jnp.pad(idx.astype(jnp.int32), [(0, 0)] * (idx.ndim - 1)
+                   + [(0, pad)])
 
 
 # ---------------------------------------------------------------------------
 # gather_rows: out[i] = table[idx[i]]
 # ---------------------------------------------------------------------------
 
-def _gather_rows_kernel(idx_ref, table_ref, out_ref):
-    # table_ref block = (1, dblk) row slice steered by index_map; copy out.
-    out_ref[...] = table_ref[...]
-
-
-def _gather_rows_call(table: jnp.ndarray, idx: jnp.ndarray, dblk: int,
-                      interpret: bool) -> jnp.ndarray:
-    n = idx.shape[0]
-    d = table.shape[1]
-    return pl.pallas_call(
-        _gather_rows_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n, d // dblk),
-            in_specs=[
-                pl.BlockSpec((1, dblk), lambda i, j, idx_ref: (idx_ref[i], j)),
-            ],
-            out_specs=pl.BlockSpec((1, dblk), lambda i, j, idx_ref: (i, j)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((n, d), table.dtype),
-        interpret=interpret,
-    )(idx, table)
+def _gather_rows_kernel(idx_ref, table_hbm, out_ref, sem, *, n: int):
+    count = jnp.minimum(ROWS_PER_STEP, n - pl.program_id(0) * ROWS_PER_STEP)
+    _dma_rows(idx_ref, table_hbm, out_ref, sem, count)
 
 
 def gather_rows(table: jnp.ndarray, idx: jnp.ndarray,
                 interpret: bool = False) -> jnp.ndarray:
     """table: (R, d), idx: (n,) int32 -> (n, d)."""
-    d = table.shape[1]
-    parts = [_gather_rows_call(table[:, off:off + w], idx, blk, interpret)
-             for off, w, blk in _dim_splits(d)]
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+    n, d = idx.shape[0], table.shape[1]
+    words = _as_words(table)
+    w = words.shape[-1]
+    idx_p = _padded(idx)
+    out = pl.pallas_call(
+        functools.partial(_gather_rows_kernel, n=n),
+        grid=(idx_p.shape[0] // ROWS_PER_STEP,),
+        in_specs=[pl.BlockSpec((ROWS_PER_STEP,), lambda i: (i,),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((ROWS_PER_STEP, 1, w), lambda i: (i, 0, 0)),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        out_shape=jax.ShapeDtypeStruct((idx_p.shape[0], 1, w), words.dtype),
+        interpret=interpret,
+    )(idx_p, words)
+    return _from_words(out, n, table.dtype, d)
 
 
 # ---------------------------------------------------------------------------
 # gather_agg: out[i] = reduce_j table[idx[i, j]]
 # ---------------------------------------------------------------------------
 
-def _gather_agg_kernel(idx_ref, table_ref, out_ref, *, fanout: int,
-                       reduce: str):
+def _gather_agg_kernel(idx_ref, table_hbm, out_ref, rows_ref, sem, *,
+                       n: int, fanout: int, reduce: str):
     j = pl.program_id(1)  # fanout position (innermost revisits out block)
-    row = table_ref[...].astype(jnp.float32)
+    count = jnp.minimum(ROWS_PER_STEP, n - pl.program_id(0) * ROWS_PER_STEP)
+    _dma_rows(idx_ref, table_hbm, rows_ref, sem, count)
+    row = rows_ref[...]
 
     @pl.when(j == 0)
     def _init():
@@ -117,33 +146,31 @@ def gather_agg(table: jnp.ndarray, idx: jnp.ndarray, reduce: str = "sum",
                interpret: bool = False) -> jnp.ndarray:
     """table: (R, d), idx: (n, f) int32 -> (n, d) reduced over f.
 
-    Grid is (n, f, d_tiles); the output block (i, :) stays resident in VMEM
+    Grid is (row blocks, f); the output block stays resident in VMEM
     across the f accumulation steps (TPU grids execute sequentially, so
-    revisiting an output block is the supported accumulate idiom).
+    revisiting an output block is the supported accumulate idiom). The
+    index matrix is laid out fanout-major so each step's index block is
+    contiguous. Accumulation is f32, so the table is read as f32 rows.
     """
     n, f = idx.shape
     d = table.shape[1]
-    kern = functools.partial(_gather_agg_kernel, fanout=f, reduce=reduce)
-
-    def call(table_part: jnp.ndarray, dblk: int) -> jnp.ndarray:
-        dd = table_part.shape[1]
-        return pl.pallas_call(
-            kern,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(n, f, dd // dblk),
-                in_specs=[
-                    pl.BlockSpec((1, dblk),
-                                 lambda i, j, t, idx_ref: (idx_ref[i, j], t)),
-                ],
-                out_specs=pl.BlockSpec((1, dblk),
-                                       lambda i, j, t, idx_ref: (i, t)),
-            ),
-            out_shape=jax.ShapeDtypeStruct((n, dd), jnp.float32),
-            interpret=interpret,
-        )(idx, table_part)
-
-    parts = [call(table[:, off:off + w], blk)
-             for off, w, blk in _dim_splits(d)]
-    out = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
-    return out.astype(table.dtype)
+    words = _as_words(table.astype(jnp.float32))
+    w = words.shape[-1]
+    idx_p = _padded(idx.T).reshape(-1)                 # (f * n_pad,)
+    blocks = idx_p.shape[0] // f // ROWS_PER_STEP
+    out = pl.pallas_call(
+        functools.partial(_gather_agg_kernel, n=n, fanout=f, reduce=reduce),
+        grid=(blocks, f),
+        in_specs=[pl.BlockSpec((ROWS_PER_STEP,),
+                               lambda i, j: (j * blocks + i,),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((ROWS_PER_STEP, 1, w),
+                               lambda i, j: (i, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((ROWS_PER_STEP, 1, w), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
+        out_shape=jax.ShapeDtypeStruct((blocks * ROWS_PER_STEP, 1, w),
+                                       jnp.float32),
+        interpret=interpret,
+    )(idx_p, words)
+    return _from_words(out, n, jnp.float32, d).astype(table.dtype)

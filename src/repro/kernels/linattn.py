@@ -31,15 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Renamed upstream: older jax ships TPUCompilerParams, newer CompilerParams.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))
-if _COMPILER_PARAMS is None:
-    def _COMPILER_PARAMS(**kwargs):
-        raise AttributeError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams "
-            "nor TPUCompilerParams — unsupported jax version for linattn")
-
 
 def _linattn_kernel(q_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref,
                     s_ref, *, chunk: int, nchunks: int):
@@ -109,7 +100,7 @@ def linattn_chunked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             jax.ShapeDtypeStruct((BH, dk, dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, w, u2)

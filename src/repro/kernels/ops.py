@@ -1,15 +1,18 @@
 """Dispatching wrappers around the Pallas kernels.
 
-Call sites never touch `pallas_call` directly: each op picks the best
-implementation for the runtime platform —
+Call sites never touch `pallas_call` directly. The gather ops are chosen
+by the platform the program is *compiled for* (``lax.platform_dependent``),
+not by the process's default backend, so a step compiled for a TPU always
+holds the Pallas kernel —
 
   * TPU      → the Pallas kernel (compiled),
   * CPU/test → the pure-jnp oracle (ref.py), or the kernel in interpret
                mode when ``force_kernel=True`` (how tests exercise it).
 
-The jnp paths are differentiable; training uses them (the chunked
-formulation is matmul-parallel in jnp too). The Pallas kernels are the
-serving/TPU fast path.
+Training on the TPU gathers with the kernel: the gathered table holds
+input features, which are never differentiated, so the kernel needs no
+VJP. Linear attention trains through the differentiable jnp chunked form;
+its Pallas kernel serves prefill on the TPU.
 """
 from __future__ import annotations
 
@@ -30,21 +33,21 @@ def _on_tpu() -> bool:
 def gather_rows(table: jnp.ndarray, idx: jnp.ndarray,
                 force_kernel: bool = False) -> jnp.ndarray:
     """out[i] = table[idx[i]]."""
-    if _on_tpu():
-        return _ga.gather_rows(table, idx)
-    if force_kernel:
-        return _ga.gather_rows(table, idx, interpret=True)
-    return _ref.gather_rows_ref(table, idx)
+    other = (functools.partial(_ga.gather_rows, interpret=True)
+             if force_kernel else _ref.gather_rows_ref)
+    return jax.lax.platform_dependent(table, idx, tpu=_ga.gather_rows,
+                                      default=other)
 
 
 def gather_agg(table: jnp.ndarray, idx: jnp.ndarray, reduce: str = "sum",
                force_kernel: bool = False) -> jnp.ndarray:
     """out[i] = reduce_j table[idx[i, j]] (fused gather + segment reduce)."""
-    if _on_tpu():
-        return _ga.gather_agg(table, idx, reduce=reduce)
-    if force_kernel:
-        return _ga.gather_agg(table, idx, reduce=reduce, interpret=True)
-    return _ref.gather_agg_ref(table, idx, reduce=reduce)
+    other = (functools.partial(_ga.gather_agg, reduce=reduce, interpret=True)
+             if force_kernel
+             else functools.partial(_ref.gather_agg_ref, reduce=reduce))
+    return jax.lax.platform_dependent(
+        table, idx, tpu=functools.partial(_ga.gather_agg, reduce=reduce),
+        default=other)
 
 
 # ---------------------------------------------------------------------------

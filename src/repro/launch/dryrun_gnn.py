@@ -72,8 +72,6 @@ def main() -> None:
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):        # older jax: one dict per computation
-        cost = cost[0] if cost else {}
     coll = collective_bytes(compiled.as_text())
     rec = {
         "kind": "hopgnn_gnn_iteration",

@@ -12,9 +12,10 @@ trace-event JSON format — load the file at https://ui.perfetto.dev or
 - ``planner-N``       planner fan-out pool threads (when cores allow)
 
 ``run_manifest()`` stamps artifacts with git sha, jax/numpy/python
-versions, and platform so any BENCH_*.json or trace file can be matched
-to the commit that produced it; ``write_metrics_jsonl()`` emits a
-manifest header line followed by one JSON object per row.
+versions, host platform and the JAX device, so any BENCH_*.json or trace
+file can be matched to the commit and the device that produced it;
+``write_metrics_jsonl()`` emits a manifest header line followed by one
+JSON object per row.
 """
 from __future__ import annotations
 
@@ -56,24 +57,21 @@ def config_digest(config) -> str:
 def run_manifest(seed: Optional[int] = None, config=None,
                  extra: Optional[dict] = None) -> dict:
     """Provenance stamp shared by every artifact writer: git sha,
-    interpreter + library versions, platform, optional seed and config
-    digest."""
-    try:
-        import jax
-        jax_ver = jax.__version__
-    except Exception:                                   # noqa: BLE001
-        jax_ver = "unavailable"
-    try:
-        import numpy as np
-        np_ver = np.__version__
-    except Exception:                                   # noqa: BLE001
-        np_ver = "unavailable"
+    interpreter + library versions, host platform, the JAX device the run
+    used (``device``: platform, device_kind, count), optional seed and
+    config digest."""
+    import jax
+    import numpy as np
+    devices = jax.devices()
     m = {
         "git_sha": _git_sha(),
         "python": platform.python_version(),
-        "jax": jax_ver,
-        "numpy": np_ver,
+        "jax": jax.__version__,
+        "numpy": np.__version__,
         "platform": platform.platform(),
+        "device": {"platform": devices[0].platform,
+                   "device_kind": devices[0].device_kind,
+                   "count": len(devices)},
         "argv": " ".join(sys.argv),
         "time_unix": round(time.time(), 3),
     }

@@ -68,6 +68,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import latest_step, load_checkpoint, save_checkpoint
 from repro.core import distributed as engine
@@ -89,6 +90,20 @@ from repro.resilience import (BackgroundError, CheckpointRollbackExhausted,
 from repro.resilience import faults as _rfaults
 from repro.resilience.faults import InjectedFault
 from repro.train.budget import ShapeBudget
+
+
+def _device_failure(e: Optional[BaseException]) -> bool:
+    """True when a runtime error of the device (HBM exhausted, a failed
+    launch or transfer) is anywhere in ``e``'s cause chain. A replay or a
+    ladder rung would run the same programs on the same device again, so
+    such an error is raised, never absorbed."""
+    seen = set()
+    while e is not None and id(e) not in seen:
+        if isinstance(e, jax.errors.JaxRuntimeError):
+            return True
+        seen.add(id(e))
+        e = e.__cause__ or e.__context__
+    return False
 
 
 @dataclasses.dataclass
@@ -145,6 +160,7 @@ class EpochStats:
     membership_generation: int = 0   # world generation at epoch end
     membership_recoveries: int = 0   # confirmed peer deaths recovered while
     #                                  running this epoch (rejoin or shrink)
+    iter_losses: tuple = ()     # per-iteration losses, in order
 
 
 class Trainer:
@@ -200,8 +216,12 @@ class Trainer:
                 "exchange gathers from a device-resident table, which "
                 "out-of-core mode exists to avoid")
         # device-resident once: re-uploading the feature table every
-        # iteration was part of the per-step overhead this subsystem removes
-        self.table = (jnp.asarray(self.store.as_dense())
+        # iteration was part of the per-step overhead this subsystem removes.
+        # Under a mesh every device holds its own shard of the table (and
+        # of the cache table and each plan's device args).
+        self.mesh = mesh
+        self._sharding = engine.shard_sharding(mesh)
+        self.table = (engine._as_device(self.store.as_dense(), self._sharding)
                       if self.store.resident else None)
         self.cfg = cfg
         self.optimizer = optimizer or adamw(1e-3)
@@ -225,13 +245,18 @@ class Trainer:
             params = jax.tree.map(jnp.array, params)
         self.params = params
         self.opt_state = self.optimizer.init(self.params)
+        if mesh is not None:
+            # replicated where the sharded step keeps them, so its first
+            # call compiles the same program as every later one
+            self.params, self.opt_state = jax.device_put(
+                (self.params, self.opt_state),
+                NamedSharding(mesh, P()))
         self._uploader = None          # PlanUploader, created in fit()
         self._empty_cache = None       # shared (N, 0, d) cache-off table
         self.strategy: Strategy = strategy
         self.pregather = pregather
         self.merging = (strategy == "hopgnn") if merging is None else merging
         self.selector = selector
-        self.mesh = mesh
         self.budget = budget if budget is not None else ShapeBudget()
         self.train_vertices = (None if train_vertices is None
                                else np.asarray(train_vertices))
@@ -286,7 +311,7 @@ class Trainer:
                 # refreshes never retrace
                 self.cache_store = CacheStore(
                     self.num_shards, d, c_max=next_bucket(self.cache_rows),
-                    dtype=self.store.dtype)
+                    dtype=self.store.dtype, sharding=self._sharding)
                 self._cache_policy = make_policy(
                     cache_policy, graph=self.graph, owner=self.owner,
                     num_shards=self.num_shards)
@@ -641,7 +666,8 @@ class Trainer:
         disabled cache and (streamed mode) the absent feature table."""
         if self._empty_cache is None:
             self._empty_cache = engine.empty_cache_table(
-                self.num_shards, self.store.feature_dim, self.store.dtype)
+                self.num_shards, self.store.feature_dim, self.store.dtype,
+                self._sharding)
         return self._empty_cache
 
     def train_step(self, plan: IterationPlan):
@@ -670,7 +696,7 @@ class Trainer:
                                                      self.fold_returns),
             streamed=bool(getattr(plan, "streamed", False)))
         table, cache_tab, dev, denom = engine.prepare_iteration_args(
-            self.table, plan, cache_tab)
+            self.table, plan, cache_tab, self._sharding)
         self.params, self.opt_state, loss = fn(
             self.params, self.opt_state, table, cache_tab, dev, denom)
         self.global_step += 1
@@ -704,8 +730,7 @@ class Trainer:
             fold_returns=engine.resolve_fold_returns(p0, self.fold_returns),
             stacked=True, streamed=bool(getattr(p0, "streamed", False)))
         dev_stack, denoms = stack_committed(plans)
-        table = (engine._as_device(self.table) if self.table is not None
-                 else self._empty_table())
+        table = self.table if self.table is not None else self._empty_table()
         self.params, self.opt_state, losses = fn(
             self.params, self.opt_state, table,
             cache_tab, dev_stack, denoms)
@@ -939,7 +964,7 @@ class Trainer:
         self.local_idx = wr.local_idx
         self.store = self.store.reshard(wr.part, wr.num_shards)
         self.streamed = not self.store.resident
-        self.table = (jnp.asarray(self.store.as_dense())
+        self.table = (engine._as_device(self.store.as_dense(), self._sharding)
                       if self.store.resident else None)
         self._empty_cache = None       # the (N, 0, d) table is world-shaped
         # merge controller: the base rotation assignment is world-shaped;
@@ -953,7 +978,8 @@ class Trainer:
             from repro.train.budget import next_bucket
             self.cache_store = CacheStore(
                 self.num_shards, self.store.feature_dim,
-                c_max=next_bucket(self.cache_rows), dtype=self.store.dtype)
+                c_max=next_bucket(self.cache_rows), dtype=self.store.dtype,
+                sharding=self._sharding)
             self._cache_policy = make_policy(
                 self.cache_policy_name, graph=self.graph, owner=self.owner,
                 num_shards=self.num_shards)
@@ -968,7 +994,8 @@ class Trainer:
             # ping-pong upload buffers are plan-shaped; rebuild fresh
             from repro.train.pipeline import PlanUploader
             self._uploader = PlanUploader(budget=self.budget,
-                                          view=self.membership)
+                                          view=self.membership,
+                                          sharding=self._sharding)
         self.membership.shrink(dead, epoch=epoch)
 
     def _resume_shared_checkpoint(self) -> bool:
@@ -1042,7 +1069,8 @@ class Trainer:
                     cache_exec, submit)
                 break
             except self._RECOVERABLE as e:
-                if attempts >= self.resilience.max_epoch_attempts:
+                if (attempts >= self.resilience.max_epoch_attempts
+                        or _device_failure(e)):
                     raise
                 rung = self._recover(e, epoch)
                 if rung is not None:
@@ -1161,7 +1189,8 @@ class Trainer:
         if self.pipeline and self._uploader is None:
             from repro.train.pipeline import PlanUploader
             self._uploader = PlanUploader(budget=self.budget,
-                                          view=self.membership)
+                                          view=self.membership,
+                                          sharding=self._sharding)
         # the cache refresh computation gets its own thread: it must not
         # block the plan double-buffer (and vice versa). The tiered store's
         # readahead forecast shares it (both are epoch-boundary jobs on the
@@ -1193,6 +1222,7 @@ class Trainer:
                 row_bytes = self.store.row_bytes
                 st = EpochStats(epoch=epoch,
                                 loss=sum(res.losses) / iters_per_epoch,
+                                iter_losses=tuple(res.losses),
                                 time_s=res.wall_s,
                                 steady_time_s=steady_epoch,
                                 traces=res.traces,

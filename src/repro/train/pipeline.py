@@ -79,10 +79,12 @@ class PlanUploader:
     signature exactly when the bucket itself grew.
     """
 
-    def __init__(self, budget=None, strict: bool = False, view=None):
+    def __init__(self, budget=None, strict: bool = False, view=None,
+                 sharding=None):
         self.budget = budget
         self.strict = strict
         self.view = view               # MembershipView (world-stale refusal)
+        self.sharding = sharding       # per-shard placement under a mesh
         self._sigs: dict = {}          # pattern (num_steps) -> signature
         self._buckets: dict = {}       # pattern -> bucket_shapes snapshot
         self.uploads = 0
@@ -111,9 +113,8 @@ class PlanUploader:
                         f"{plan.c_max}, {l_max}) drifted from budget bucket "
                         f"({bp}, {rm}, {cm}, {lm}) for pattern "
                         f"{plan.num_steps}")
-        dev = jax.tree.map(
-            lambda x: x if isinstance(x, jax.Array) else jax.device_put(x),
-            plan.device_args())
+        dev = jax.tree.map(lambda x: engine._as_device(x, self.sharding),
+                           plan.device_args())
         denom = jax.device_put(np.float32(plan.global_batch))
         sig = engine._shape_sig(dev)
         key = plan.num_steps

@@ -7,7 +7,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import repro.core.pregather as pg
 from repro.cache import (CacheIndex, CacheStore, DegreePolicy, EpochPrefetcher,

@@ -4,7 +4,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import plan_iteration, run_iteration, MergingController
 from repro.core.comm_model import (ModelSpec, alpha_ratio, hopgnn_bytes,

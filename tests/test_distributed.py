@@ -171,6 +171,57 @@ def test_per_step_iteration_collective_counts():
     assert res["pregather"] == 2
 
 
+def test_trainer_mesh_places_each_shard_on_its_device():
+    """Trainer under a four-device mesh: the feature table, the cache table
+    and every committed plan array hold one shard per device (no reshard
+    out of device 0 each step), params are replicated, and losses match
+    the emulated run."""
+    res = _run_subprocess("""
+        import json
+        import numpy as np, jax
+        from repro.graph import make_dataset, ldg_partition
+        from repro.graph.partition import shard_features
+        from repro.models.gnn import GNNConfig
+        from repro.train import Trainer
+
+        ds = make_dataset('arxiv', scale=0.01, seed=0)
+        n = 4
+        part = ldg_partition(ds.graph, n, passes=1)
+        table, owner, local_idx = shard_features(ds.features, part, n)
+        cfg = GNNConfig(model='sage', num_layers=2, hidden_dim=8,
+                        feature_dim=ds.feature_dim,
+                        num_classes=ds.num_classes, fanout=2)
+
+        def fit(mesh):
+            tr = Trainer(graph=ds.graph, labels=ds.labels, part=part,
+                         owner=owner, local_idx=local_idx, table=table,
+                         cfg=cfg, mesh=mesh,
+                         train_vertices=ds.train_vertices())
+            stats = tr.fit(epochs=2, iters_per_epoch=2, batch_per_model=4)
+            return tr, [v for st in stats for v in st.iter_losses]
+
+        _, emulated = fit(None)
+        tr, sharded = fit(jax.make_mesh((n,), ('data',)))
+        plan = tr.build_plan(2, 0, 4)
+        arrays = [tr.table, tr._empty_table()] + jax.tree.leaves(
+            plan.committed['dev'])
+        split = all(
+            len({s.device for s in a.addressable_shards}) == n
+            and all(s.data.shape == (1,) + a.shape[1:]
+                    for s in a.addressable_shards) for a in arrays)
+        replicated = all(a.sharding.is_fully_replicated
+                         for a in jax.tree.leaves(tr.params))
+        print('RESULT:' + json.dumps({
+            'split': split, 'arrays': len(arrays),
+            'replicated': replicated,
+            'emulated': emulated, 'sharded': sharded}))
+    """, devices=4)
+    assert res["split"] and res["arrays"] >= 5
+    assert res["replicated"]
+    assert len(res["sharded"]) == 4
+    assert res["sharded"] == pytest.approx(res["emulated"], rel=1e-5)
+
+
 @pytest.mark.slow
 def test_transformer_sharded_train_step_runs():
     """A reduced transformer train step under a real 4×2 mesh with the

@@ -2,7 +2,7 @@
 tests with hypothesis)."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.graph import (CSRGraph, hash_partition, ldg_partition,
                          make_dataset, range_partition, sample_tree_block)

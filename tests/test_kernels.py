@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops
 from repro.kernels.gather_agg import gather_agg, gather_rows
@@ -41,27 +41,52 @@ def test_gather_agg_sweep(n, f, d, reduce, dtype):
                                atol=2e-2 if dtype == jnp.bfloat16 else 1e-5)
 
 
-def test_dim_splits_lane_tiling():
-    """d > LANE with d % LANE != 0 must tile the first d//LANE*LANE lanes
-    at LANE width and carry only the tail as a sub-lane block (the old
-    fallback put the whole dim in one block)."""
-    from repro.kernels.gather_agg import LANE, _dim_splits
-    assert _dim_splits(128) == [(0, 128, 128)]
-    assert _dim_splits(256) == [(0, 256, 128)]
-    assert _dim_splits(96) == [(0, 96, 96)]
-    assert _dim_splits(192) == [(0, 128, LANE), (128, 64, 64)]
-    assert _dim_splits(300) == [(0, 256, LANE), (256, 44, 44)]
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_dim_splits_lane_tiling(dtype):
+    """The kernels DMA whole rows of 32-bit words padded to lane tiles:
+    every width maps to a (R, 1, w) view with w % LANE == 0, 16-bit rows
+    pack two values per word, and the view round-trips bit-exactly."""
+    from repro.kernels.gather_agg import LANE, _as_words, _from_words
+    per_word = 4 // jnp.dtype(dtype).itemsize
+    rng = np.random.default_rng(8)
+    for d, w in [(128, 128), (256, 256), (96, 128), (192, 256),
+                 (300, 384), (600, 640)]:
+        table = jnp.asarray(rng.standard_normal((5, d)), dtype)
+        words = _as_words(table)
+        assert words.shape == (5, 1, -(-w // per_word // LANE) * LANE)
+        np.testing.assert_array_equal(
+            np.asarray(_from_words(words, 5, table.dtype, d)),
+            np.asarray(table))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_gather_rows_d192_lane_split(dtype):
-    """d = 192: 128-lane tile + 64-wide tail, stitched back bit-exact."""
+    """d = 192: rows padded to two lane tiles, sliced back bit-exact."""
     rng = np.random.default_rng(6)
     table = jnp.asarray(rng.standard_normal((31, 192)), dtype)
     idx = jnp.asarray(rng.integers(0, 31, 27), jnp.int32)
     out = gather_rows(table, idx, interpret=True)
     np.testing.assert_array_equal(np.asarray(out),
                                   np.asarray(gather_rows_ref(table, idx)))
+
+
+@pytest.mark.parametrize("op", ["rows", "agg"])
+def test_gather_spans_grid_steps(op):
+    """More indices than one grid step DMAs, ending in a partial block:
+    every block gathers its own rows and the tail stops at n."""
+    from repro.kernels.gather_agg import ROWS_PER_STEP
+    rng = np.random.default_rng(9)
+    n = 2 * ROWS_PER_STEP + 37
+    table = jnp.asarray(rng.standard_normal((300, 100)), jnp.float32)
+    if op == "rows":
+        idx = jnp.asarray(rng.integers(0, 300, n), jnp.int32)
+        out, ref = (gather_rows(table, idx, interpret=True),
+                    gather_rows_ref(table, idx))
+    else:
+        idx = jnp.asarray(rng.integers(0, 300, (n, 3)), jnp.int32)
+        out, ref = (gather_agg(table, idx, interpret=True),
+                    gather_agg_ref(table, idx))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
 
 
 @pytest.mark.parametrize("reduce", ["sum", "mean", "max"])

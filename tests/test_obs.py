@@ -199,10 +199,15 @@ def test_config_digest_stable_and_order_free():
 
 def test_run_manifest_keys():
     m = run_manifest(seed=7, config={"x": 1}, extra={"note": "t"})
-    for k in ("git_sha", "python", "jax", "numpy", "platform", "argv",
-              "time_unix"):
+    for k in ("git_sha", "python", "jax", "numpy", "platform", "device",
+              "argv", "time_unix"):
         assert k in m, k
     assert m["seed"] == 7 and m["note"] == "t"
+    dev = jax.devices()[0]
+    assert m["device"] == {"platform": dev.platform,
+                           "device_kind": dev.device_kind,
+                           "count": len(jax.devices())}
+    json.dumps(m)                      # artifacts embed it as JSON
     assert len(m["config_digest"]) == 12
 
 

@@ -4,12 +4,11 @@ The vectorized ``build_gather_plan`` (bitmap/sort dedup + SlotMap) must
 produce byte-identical plans to the kept per-vertex reference
 implementation: same req layout, same counts, same slot assignments, same
 overflow behavior — on random graphs, through both dedup paths, and for
-the hop translation. Property-tested via hypothesis when available, plus a
-seeded sweep that always runs.
+the hop translation. Property-tested via hypothesis, plus a seeded sweep.
 """
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import repro.core.pregather as pg
 from repro.core.pregather import (SlotMap, _reference_build_gather_plan,

@@ -200,6 +200,25 @@ def test_persistent_readahead_fault_degrades_to_resident_gather(
     _assert_params_equal(tr, ctr)
 
 
+def test_device_error_in_uploader_is_raised_not_degraded(partitioned,
+                                                         monkeypatch):
+    """A device runtime error (here a simulated HBM exhaustion) in the
+    uploader thread's transfer is raised from fit: no replay and no ladder
+    rung hides it behind a slower mode."""
+    from repro.train.pipeline import PlanUploader
+
+    def oom(self, plan):
+        raise jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: out of memory allocating plan buffers")
+    monkeypatch.setattr(PlanUploader, "commit", oom)
+    tr = _trainer(partitioned, _cfg(partitioned))
+    with pytest.raises(BackgroundError) as info:
+        tr.fit(epochs=2, iters_per_epoch=4, batch_per_model=8)
+    assert isinstance(info.value.__cause__, jax.errors.JaxRuntimeError)
+    assert tr.degradations_taken == []
+    assert tr.pipeline
+
+
 # ---------------------------------------------------------------------------
 # The headline gate: mixed recoverable plan, streamed store, bit-identical
 # ---------------------------------------------------------------------------
