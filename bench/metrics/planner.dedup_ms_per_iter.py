@@ -1,0 +1,17 @@
+"""Host time per plan built in the traced window in the planner's
+``planner.dedup`` stage: the pre-gather's dedup of every shard's needed
+rows into one request list per peer (``build_gather_plan``), or the per-
+step plans. The program records the stage as a span on the building
+thread, once per plan, nested in ``plan.build``; the four stages split
+``planner.ms_per_iter``. A faster stage raises ``roots_per_s`` where the
+host planner sets the pace."""
+from bench import scopes
+
+LAYER = "planner"
+MOVES = "roots_per_s"
+UNIT = "ms"
+
+
+def read(run):
+    ns = scopes.span_ns_per_build(run.record, "planner.dedup")
+    return None if ns is None else ns / 1e6

@@ -69,6 +69,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.models.gnn.models import GNNConfig, gnn_forward, gnn_loss
 from repro.obs import metrics as _obs_metrics
 from repro.obs import trace as _obs_trace
+from repro.obs.scopes import EXCHANGE, LAYERS, UPDATE
 
 
 def tree_add(a, b):
@@ -235,8 +236,8 @@ def _shard_grads(params, cfg: GNNConfig, workspace_fn: Callable,
 
     def loss_fn(p, ws, idxs, lab, w):
         feats = [ops.gather_rows(ws, i) for i in idxs]
-        loss, logits = gnn_loss(p, cfg, feats, lab, weight=w)
-        return loss, logits
+        with jax.named_scope(LAYERS):
+            return gnn_loss(p, cfg, feats, lab, weight=w)
 
     def step(carry, t):
         gacc, lacc = carry
@@ -260,32 +261,39 @@ def _iteration_shard(params, table, cache, dev, cfg: GNNConfig,
     the workspace is assembled as [local | cached | fetched], matching the
     planner's slot layout. ``denom`` is the true global batch size as a
     traced scalar (not static — see module doc)."""
-    base = jnp.concatenate([table, cache], 0)     # [local | cached]
     d = table.shape[1]
-    if pregather:
-        recv = comm.exchange(table, dev["req"])            # (P, r_max, d)
-        ws = jnp.concatenate([base, recv.reshape(-1, d)], 0)
-        workspace_fn = lambda t: ws
-    else:
-        # All T index requests ship in one batched all_to_all before the
-        # time-step scan; the scan body then only pays the feature-return
-        # collective — T+1 all_to_alls per iteration instead of 2T. With
-        # fold_returns the T returns also collapse into one pre-scan
-        # collective: exactly 2 all_to_alls per iteration.
-        incoming = comm.exchange_indices_batched(dev["step_req"])
-        if fold_returns:
-            recv_all = comm.serve_features_batched(table, incoming)
-            def workspace_fn(t):
-                return jnp.concatenate(
-                    [base, recv_all[t].reshape(-1, d)], 0)
+    with jax.named_scope(EXCHANGE):
+        base = jnp.concatenate([table, cache], 0)     # [local | cached]
+        if pregather:
+            recv = comm.exchange(table, dev["req"])        # (P, r_max, d)
+            ws = jnp.concatenate([base, recv.reshape(-1, d)], 0)
+            workspace_fn = lambda t: ws
         else:
-            def workspace_fn(t):
-                recv = comm.serve_features(table, incoming[t])
-                return jnp.concatenate([base, recv.reshape(-1, d)], 0)
+            # All T index requests ship in one batched all_to_all before
+            # the time-step scan; the scan body then only pays the
+            # feature-return collective — T+1 all_to_alls per iteration
+            # instead of 2T. With fold_returns the T returns also collapse
+            # into one pre-scan collective: exactly 2 all_to_alls per
+            # iteration.
+            incoming = comm.exchange_indices_batched(dev["step_req"])
+            if fold_returns:
+                recv_all = comm.serve_features_batched(table, incoming)
+
+                # workspace_fn runs in the scan body, outside this block
+                @jax.named_scope(EXCHANGE)
+                def workspace_fn(t):
+                    return jnp.concatenate(
+                        [base, recv_all[t].reshape(-1, d)], 0)
+            else:
+                @jax.named_scope(EXCHANGE)
+                def workspace_fn(t):
+                    recv = comm.serve_features(table, incoming[t])
+                    return jnp.concatenate([base, recv.reshape(-1, d)], 0)
     grads, loss_sum = _shard_grads(params, cfg, workspace_fn,
                                    dev["hop_idx"], dev["labels"], dev["weights"])
-    grads = comm.grad_mean(grads, denom)
-    loss = jax.lax.psum(loss_sum, comm.axis) / denom
+    with jax.named_scope(UPDATE):
+        grads = comm.grad_mean(grads, denom)
+        loss = jax.lax.psum(loss_sum, comm.axis) / denom
     return grads, loss
 
 
@@ -723,13 +731,15 @@ def _streamed_shard(params, cache, dev, cfg: GNNConfig, denom,
     plan-carried feature blocks — ``[local_compact | cached | fetched]`` —
     so no feature collective runs; only the gradient psum remains."""
     d = dev["feat_local"].shape[-1]
-    ws = jnp.concatenate([dev["feat_local"], cache,
-                          dev["feat_fetch"].reshape(-1, d)], 0)
+    with jax.named_scope(EXCHANGE):
+        ws = jnp.concatenate([dev["feat_local"], cache,
+                              dev["feat_fetch"].reshape(-1, d)], 0)
     grads, loss_sum = _shard_grads(params, cfg, lambda t: ws,
                                    dev["hop_idx"], dev["labels"],
                                    dev["weights"])
-    grads = comm.grad_mean(grads, denom)
-    loss = jax.lax.psum(loss_sum, comm.axis) / denom
+    with jax.named_scope(UPDATE):
+        grads = comm.grad_mean(grads, denom)
+        loss = jax.lax.psum(loss_sum, comm.axis) / denom
     return grads, loss
 
 
@@ -752,7 +762,9 @@ def _build_fused(cfg: GNNConfig, pregather: bool, fold_returns: bool,
     if not stacked:
         def step(params, opt_state, table, cache, dev, denom):
             grads, loss = grads_fn(params, table, cache, dev, denom)
-            new_params, new_state = optimizer.update(grads, opt_state, params)
+            with jax.named_scope(UPDATE):
+                new_params, new_state = optimizer.update(grads, opt_state,
+                                                         params)
             return new_params, new_state, loss
 
         return jax.jit(step, donate_argnums=(0, 1))
@@ -762,7 +774,8 @@ def _build_fused(cfg: GNNConfig, pregather: bool, fold_returns: bool,
             p, s = carry
             dev, denom = x
             grads, loss = grads_fn(p, table, cache, dev, denom)
-            p2, s2 = optimizer.update(grads, s, p)
+            with jax.named_scope(UPDATE):
+                p2, s2 = optimizer.update(grads, s, p)
             return (p2, s2), loss
 
         (p, s), losses = jax.lax.scan(body, (params, opt_state),
@@ -831,16 +844,18 @@ def _emulated_streamed_iteration(params, cache_g, dev, denom,
     d = dev["feat_local"].shape[-1]
     per_shard = []
     for s in range(n):
-        ws = jnp.concatenate([dev["feat_local"][s], cache_g[s],
-                              dev["feat_fetch"][s].reshape(-1, d)], 0)
+        with jax.named_scope(EXCHANGE):
+            ws = jnp.concatenate([dev["feat_local"][s], cache_g[s],
+                                  dev["feat_fetch"][s].reshape(-1, d)], 0)
         hop_idx = [h[s] for h in dev["hop_idx"]]
         g, l = _shard_grads(params, cfg, lambda t, ws=ws: ws, hop_idx,
                             dev["labels"][s], dev["weights"][s])
         per_shard.append((g, l))
-    grads_g = jax.tree.map(lambda *xs: jnp.stack(xs),
-                           *[g for g, _ in per_shard])
-    grads = ecomm.grad_mean_global(grads_g, denom)
-    loss = sum(l for _, l in per_shard) / denom
+    with jax.named_scope(UPDATE):
+        grads_g = jax.tree.map(lambda *xs: jnp.stack(xs),
+                               *[g for g, _ in per_shard])
+        grads = ecomm.grad_mean_global(grads_g, denom)
+        loss = sum(l for _, l in per_shard) / denom
     return grads, loss
 
 
@@ -850,34 +865,44 @@ def _emulated_iteration(params, table_g, cache_g, dev, denom, cfg: GNNConfig,
     ecomm = EmulatedComm()
     n = table_g.shape[0]
     d = table_g.shape[-1]
-    if pregather:
-        recv_g = ecomm.exchange_global(table_g, dev["req"])   # (N,P,r,d)
-    else:
-        # index exchange hoisted ahead of the scan, mirroring ShardComm's
-        # batched collective (here a pure transpose — same data movement)
-        incoming_g = ecomm.exchange_indices_batched_global(dev["step_req"])
-        if fold_returns:
-            recv_all_g = ecomm.serve_features_batched_global(table_g,
-                                                             incoming_g)
+    with jax.named_scope(EXCHANGE):
+        if pregather:
+            recv_g = ecomm.exchange_global(table_g, dev["req"])  # (N,P,r,d)
+        else:
+            # index exchange hoisted ahead of the scan, mirroring
+            # ShardComm's batched collective (here a pure transpose — same
+            # data movement)
+            incoming_g = ecomm.exchange_indices_batched_global(
+                dev["step_req"])
+            if fold_returns:
+                recv_all_g = ecomm.serve_features_batched_global(
+                    table_g, incoming_g)
     per_shard = []
     for s in range(n):
-        base = jnp.concatenate([table_g[s], cache_g[s]], 0)  # [local|cached]
-        if pregather:
-            ws = jnp.concatenate([base, recv_g[s].reshape(-1, d)], 0)
-            workspace_fn = lambda t, ws=ws: ws
-        elif fold_returns:
-            def workspace_fn(t, s=s, base=base):
-                return jnp.concatenate(
-                    [base, recv_all_g[s, t].reshape(-1, d)], 0)
-        else:
-            def workspace_fn(t, s=s, base=base):
-                recv = ecomm.serve_step_global(table_g, incoming_g, t, s)
-                return jnp.concatenate([base, recv.reshape(-1, d)], 0)
+        with jax.named_scope(EXCHANGE):
+            # [local | cached], then the fetched rows
+            base = jnp.concatenate([table_g[s], cache_g[s]], 0)
+            if pregather:
+                ws = jnp.concatenate([base, recv_g[s].reshape(-1, d)], 0)
+                workspace_fn = lambda t, ws=ws: ws
+            elif fold_returns:
+                # workspace_fn runs in the scan body, outside this block
+                @jax.named_scope(EXCHANGE)
+                def workspace_fn(t, s=s, base=base):
+                    return jnp.concatenate(
+                        [base, recv_all_g[s, t].reshape(-1, d)], 0)
+            else:
+                @jax.named_scope(EXCHANGE)
+                def workspace_fn(t, s=s, base=base):
+                    recv = ecomm.serve_step_global(table_g, incoming_g, t, s)
+                    return jnp.concatenate([base, recv.reshape(-1, d)], 0)
         hop_idx = [h[s] for h in dev["hop_idx"]]
         g, l = _shard_grads(params, cfg, workspace_fn, hop_idx,
                             dev["labels"][s], dev["weights"][s])
         per_shard.append((g, l))
-    grads_g = jax.tree.map(lambda *xs: jnp.stack(xs), *[g for g, _ in per_shard])
-    grads = ecomm.grad_mean_global(grads_g, denom)
-    loss = sum(l for _, l in per_shard) / denom
+    with jax.named_scope(UPDATE):
+        grads_g = jax.tree.map(lambda *xs: jnp.stack(xs),
+                               *[g for g, _ in per_shard])
+        grads = ecomm.grad_mean_global(grads_g, denom)
+        loss = sum(l for _, l in per_shard) / denom
     return grads, loss

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.obs.scopes import KERNEL
+
 LANE = 128  # TPU lane width; rows are padded to whole lane tiles
 
 # Rows DMA'd per grid step. It is also the SMEM index block, which must
@@ -95,22 +97,26 @@ def _gather_rows_kernel(idx_ref, table_hbm, out_ref, sem, *, n: int):
 
 def gather_rows(table: jnp.ndarray, idx: jnp.ndarray,
                 interpret: bool = False) -> jnp.ndarray:
-    """table: (R, d), idx: (n,) int32 -> (n, d)."""
+    """table: (R, d), idx: (n,) int32 -> (n, d). The Pallas call runs
+    under the ``kernel`` scope; the relayout around it does not."""
     n, d = idx.shape[0], table.shape[1]
     words = _as_words(table)
     w = words.shape[-1]
     idx_p = _padded(idx)
-    out = pl.pallas_call(
-        functools.partial(_gather_rows_kernel, n=n),
-        grid=(idx_p.shape[0] // ROWS_PER_STEP,),
-        in_specs=[pl.BlockSpec((ROWS_PER_STEP,), lambda i: (i,),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((ROWS_PER_STEP, 1, w), lambda i: (i, 0, 0)),
-        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
-        out_shape=jax.ShapeDtypeStruct((idx_p.shape[0], 1, w), words.dtype),
-        interpret=interpret,
-    )(idx_p, words)
+    with jax.named_scope(KERNEL):
+        out = pl.pallas_call(
+            functools.partial(_gather_rows_kernel, n=n),
+            grid=(idx_p.shape[0] // ROWS_PER_STEP,),
+            in_specs=[pl.BlockSpec((ROWS_PER_STEP,), lambda i: (i,),
+                                   memory_space=pltpu.SMEM),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((ROWS_PER_STEP, 1, w),
+                                   lambda i: (i, 0, 0)),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+            out_shape=jax.ShapeDtypeStruct((idx_p.shape[0], 1, w),
+                                           words.dtype),
+            interpret=interpret,
+        )(idx_p, words)
     return _from_words(out, n, table.dtype, d)
 
 

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.kernels import gather_agg as _ga
 from repro.kernels import linattn as _la
 from repro.kernels import ref as _ref
+from repro.obs.scopes import GATHER
 
 
 def _on_tpu() -> bool:
@@ -32,11 +33,12 @@ def _on_tpu() -> bool:
 
 def gather_rows(table: jnp.ndarray, idx: jnp.ndarray,
                 force_kernel: bool = False) -> jnp.ndarray:
-    """out[i] = table[idx[i]]."""
+    """out[i] = table[idx[i]], under the ``gather`` scope."""
     other = (functools.partial(_ga.gather_rows, interpret=True)
              if force_kernel else _ref.gather_rows_ref)
-    return jax.lax.platform_dependent(table, idx, tpu=_ga.gather_rows,
-                                      default=other)
+    with jax.named_scope(GATHER):
+        return jax.lax.platform_dependent(table, idx, tpu=_ga.gather_rows,
+                                          default=other)
 
 
 def gather_agg(table: jnp.ndarray, idx: jnp.ndarray, reduce: str = "sum",

@@ -7,8 +7,14 @@ Design constraints (this module sits on the training hot path):
   allocation, no clock read, no lock.
 - **Lock-free when enabled.** Each thread records into its own
   preallocated ring (``threading.local``); the hot path is two
-  ``perf_counter_ns`` reads and one list-slot store per span. The global
-  lock is touched only on first use per thread and at drain time.
+  ``perf_counter_ns`` and two ``thread_time_ns`` reads, one
+  ``jax.profiler.TraceAnnotation`` and one list-slot store per span. The
+  global lock is touched only on first use per thread and at drain time.
+- **On the profiler's clock.** While enabled, every span also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so a ``jax.profiler``
+  trace taken meanwhile stamps the span, on whatever thread it runs, on
+  the clock of its device ops. Outside a profiler trace the annotation
+  records nothing.
 - **Nesting-safe.** A per-thread depth counter stamps every span with
   its nesting level, so the exporter can rebuild the flame even though
   spans are recorded at *exit* (children land before parents).
@@ -47,6 +53,8 @@ _generation = 0                      # bumped by enable()/clear(): stale
 #                                      thread-local rings are abandoned
 _epoch_ns = 0                        # perf_counter_ns at enable/clear
 _tracks: list = []                   # live _Track registry (drain order)
+_annotation = None                   # jax.profiler.TraceAnnotation, bound
+#                                      by enable() so jax loads lazily
 _tls = threading.local()
 
 
@@ -83,7 +91,12 @@ def _get_track() -> _Track:
 @dataclass(frozen=True)
 class SpanRecord:
     """One drained record. ``kind`` is ``"X"`` (complete span) or
-    ``"i"`` (instant event); times are perf_counter_ns."""
+    ``"i"`` (instant event); times are perf_counter_ns. ``cpu_ns`` is the
+    CPU time the recording thread spent inside a span
+    (``time.thread_time_ns``); the rest of its wall time the thread was
+    off the CPU (GIL wait, I/O, preemption). None for instant events.
+    Where the kernel charges thread CPU time by scheduler tick, a span
+    reads whole ticks: only sums over many spans are meaningful there."""
     kind: str
     name: str
     track: str
@@ -91,6 +104,7 @@ class SpanRecord:
     t1_ns: int
     depth: int
     tags: Optional[dict]
+    cpu_ns: Optional[int] = None
 
     @property
     def dur_ns(self) -> int:
@@ -112,7 +126,7 @@ _NOOP = _Noop()
 
 
 class _Span:
-    __slots__ = ("name", "track", "tags", "_t0", "_tr")
+    __slots__ = ("name", "track", "tags", "_t0", "_c0", "_tr", "_ann")
 
     def __init__(self, name: str, track: Optional[str], tags):
         self.name = name
@@ -123,16 +137,23 @@ class _Span:
         tr = _get_track()
         self._tr = tr
         tr.depth += 1
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        # CPU clock read inside the wall clock reads: cpu_ns <= wall
         self._t0 = time.perf_counter_ns()
+        self._c0 = time.thread_time_ns()
         return self
 
     def __exit__(self, *exc):
+        c1 = time.thread_time_ns()
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(None, None, None)
         tr = self._tr
         tr.depth -= 1
         tr.push(("X", self.name, self.track or tr.thread,
-                 self._t0, t1, tr.depth, self.tags))
+                 self._t0, t1, tr.depth, self.tags, c1 - self._c0))
         return False
+
 
 
 def span(name: str, track: Optional[str] = None, **tags):
@@ -158,7 +179,9 @@ def enable(capacity: int = _DEFAULT_CAPACITY) -> None:
     """Start recording (drops anything previously recorded).
     ``capacity`` is the per-thread ring size; overflow overwrites the
     oldest records and is reported by :func:`dropped`."""
-    global _enabled, _capacity, _generation, _epoch_ns
+    global _enabled, _capacity, _generation, _epoch_ns, _annotation
+    from jax.profiler import TraceAnnotation
+    _annotation = TraceAnnotation
     with _lock:
         _capacity = int(capacity)
         _generation += 1

@@ -56,7 +56,7 @@ import jax
 import numpy as np
 
 from repro.core import distributed as engine
-from repro.obs.trace import event as obs_event, span as obs_span
+from repro.obs.trace import span as obs_span
 
 
 class PlanUploader:
@@ -255,7 +255,6 @@ def run_pipelined_epoch(trainer, epoch: int, iters: int,
             # this dispatch (re)traced: drain the queue and restart the
             # steady window after the sync so compile time never leaks
             # into the merging controller's signal
-            obs_event("pipeline.retrace", epoch=epoch, it=done - 1)
             with obs_span("trace.sync", epoch=epoch, it=done - 1):
                 jax.block_until_ready(trainer.params)
             window_t = time.perf_counter()

@@ -18,6 +18,7 @@ tests assert presence, never absence.
 """
 import json
 import threading
+import time
 
 import jax
 import numpy as np
@@ -143,6 +144,45 @@ def test_track_override_records_virtual_lane():
     assert rec.track == "uploader"        # not MainThread
     doc = chrome_trace()
     assert "uploader" in trace_track_names(doc)
+
+
+def test_span_cpu_time_within_wall():
+    """cpu_ns is the thread's CPU time inside the span: near the wall time
+    of a busy span, near zero for a sleeping one, never above the wall."""
+    obs_trace.enable()
+    with obs_trace.span("busy"):
+        t = time.thread_time()
+        while time.thread_time() - t < 0.02:
+            pass
+    with obs_trace.span("asleep"):
+        time.sleep(0.02)
+    obs_trace.event("mark")
+    busy, asleep, mark = obs_trace.records()
+    for r in (busy, asleep):
+        assert 0 <= r.cpu_ns <= r.dur_ns
+    assert busy.cpu_ns >= 0.02e9 and asleep.cpu_ns < 0.5 * asleep.dur_ns
+    assert mark.cpu_ns is None
+
+
+def test_spans_land_in_the_profiler_trace(tmp_path):
+    """While enabled, every span is also a profiler annotation of its name,
+    on the thread that records it."""
+    from jax.profiler import ProfileData
+    obs_trace.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_trace.span("outer.span"):
+            th = threading.Thread(
+                target=lambda: obs_trace.span("pool.span").__enter__()
+                .__exit__(None, None, None), name="plan_0")
+            th.start()
+            th.join()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    names = {e.name for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for e in line.events}
+    assert {"outer.span", "pool.span"} <= names
 
 
 # ---------------------------------------------------------------------------
@@ -337,6 +377,45 @@ def test_pipelined_spans_nest_sanely(traced_pair):
     assert all(r.depth >= 1 or r.track.startswith("plan") for r in samples)
     commits = [r for r in recs if r.name == "upload.commit"]
     assert commits and {r.track for r in commits} == {"uploader"}
+
+
+PLANNER_STAGES = ("planner.sample", "planner.dedup", "planner.translate",
+                  "planner.account")
+
+
+def test_planner_stage_spans_nest_once_per_plan(traced_pair):
+    """Each planner stage is one span per planned iteration, on the
+    building thread, directly inside its plan.build (a build that first
+    probes the shape budget plans twice)."""
+    recs = traced_pair["recs"]
+    builds = [r for r in recs if r.name == "plan.build"]
+    probes = traced_pair["tr_on"].budget.probes
+    assert builds
+    for stage in PLANNER_STAGES:
+        mine = [r for r in recs if r.name == stage]
+        nested = [r for r in mine for b in builds
+                  if r.track == b.track and r.depth == b.depth + 1
+                  and b.t0_ns <= r.t0_ns and r.t1_ns <= b.t1_ns]
+        assert len(nested) == len(mine) == len(builds) + probes, stage
+
+
+@pytest.mark.parametrize("pregather", [True, False])
+def test_planner_stage_spans_once_per_plan_iteration(partitioned, pregather):
+    from repro.core import plan_iteration
+    d = partitioned
+    rng = np.random.default_rng(0)
+    roots = [rng.choice(d["ds"].train_vertices(), 8, replace=False)
+             for _ in range(d["parts"])]
+    obs_trace.enable()
+    with obs_trace.span("plan.build"):
+        plan_iteration(d["ds"].graph, d["ds"].labels, d["part"], d["owner"],
+                       d["local_idx"], d["table"].shape[1], roots,
+                       num_layers=2, fanout=4, pregather=pregather,
+                       sample_seed=3)
+    recs = obs_trace.records()
+    stages = [r.name for r in recs if r.name.startswith("planner.")]
+    assert stages == list(PLANNER_STAGES)
+    assert all(r.depth == 1 for r in recs if r.name in PLANNER_STAGES)
 
 
 def test_epoch_stats_published_to_registry(traced_pair):
