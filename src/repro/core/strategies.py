@@ -24,13 +24,16 @@ DESIGN.md §2).
 from __future__ import annotations
 
 import dataclasses
+import itertools
+import threading
 from concurrent.futures import Executor
-from typing import Literal, Optional, Sequence
+from typing import Literal, NamedTuple, Optional, Sequence
 
 import numpy as np
 
 from repro.graph.sampler import TreeBlock, sample_tree_block
 from repro.graph.structs import CSRGraph
+from repro.obs import metrics as _obs_metrics
 from repro.obs import trace as _obs_trace
 from repro.core.micrograph import (
     AssignmentMatrix, hopgnn_assignment, lo_assignment,
@@ -64,6 +67,48 @@ def _pmap(executor: Optional[Executor], fn, items: list,
     return list(executor.map(fn, items))
 
 
+class AccountFigures(NamedTuple):
+    unique_rows: int             # deduped rows touched
+    step_unique_rows: int        # Σ per-(shard, step) unique rows
+    remote_rows_nodedup: int     # remote rows without §5.2 dedup
+
+
+class PlanAccounting:
+    """A plan's dedup-based figures, computed exactly on first read.
+
+    Training reads none of them, so the planner only keeps what they are
+    computed from: the unpadded true-root block of every (shard, step) that
+    has roots, with its shard, and ``owner``. The first reader, on whatever
+    thread, computes all three once under the lock (plans cross from the
+    prefetch thread to the main thread) and counts it on
+    ``planner.account_computed``; the blocks are then dropped."""
+
+    def __init__(self, true_blocks: list, owner: np.ndarray):
+        self._blocks = true_blocks          # [(shard, TreeBlock)], s-major
+        self._owner = owner
+        self._figures: Optional[AccountFigures] = None
+        self._lock = threading.Lock()
+
+    def figures(self) -> AccountFigures:
+        with self._lock:
+            if self._figures is None:
+                self._figures = self._compute()
+                self._blocks = None
+                _obs_metrics.inc("planner.account_computed")
+            return self._figures
+
+    def _compute(self) -> AccountFigures:
+        unique = step_unique = remote_nodedup = 0
+        for s, group in itertools.groupby(self._blocks, key=lambda b: b[0]):
+            per_step_ids = [blk.all_ids() for _, blk in group]
+            unique += np.unique(np.concatenate(per_step_ids)).size
+            for ids in per_step_ids:
+                u = np.unique(ids)
+                step_unique += u.size
+                remote_nodedup += int((self._owner[u] != s).sum())
+        return AccountFigures(int(unique), int(step_unique), remote_nodedup)
+
+
 @dataclasses.dataclass
 class IterationPlan:
     """Device-ready arrays (all stacked over the shard axis 0) + accounting.
@@ -94,10 +139,9 @@ class IterationPlan:
 
     # --- host accounting (exact, unpadded) ---
     remote_rows_exact: int               # deduped remote feature rows fetched
-    remote_rows_nodedup: int             # without §5.2 dedup (per-step uniq)
     total_rows: int                      # all feature rows touched (tree, dup)
-    unique_rows: int                     # deduped rows touched
-    step_unique_rows: int                # Σ per-(shard,step) unique rows
+    accounting: PlanAccounting           # unique_rows, step_unique_rows,
+    #                                      remote_rows_nodedup, on first read
     true_counts: np.ndarray              # (T, N) roots per (step, shard)
     assignment: AssignmentMatrix
 
@@ -134,6 +178,21 @@ class IterationPlan:
     #                                      for — attached by build_plan so
     #                                      background failures and comm
     #                                      faults carry their origin
+
+    @property
+    def unique_rows(self) -> int:
+        """Deduped feature rows touched."""
+        return self.accounting.figures().unique_rows
+
+    @property
+    def step_unique_rows(self) -> int:
+        """Σ per-(shard, step) unique feature rows."""
+        return self.accounting.figures().step_unique_rows
+
+    @property
+    def remote_rows_nodedup(self) -> int:
+        """Remote feature rows without §5.2 dedup (per-step unique)."""
+        return self.accounting.figures().remote_rows_nodedup
 
     def miss_rate(self) -> float:
         """Remote fraction of unique feature rows (paper Fig. 14)."""
@@ -292,7 +351,6 @@ def plan_iteration(graph: CSRGraph,
 
     sample_exec = executor if sample_seed is not None else None
     blocks: list[list[TreeBlock]] = [[None] * T for _ in range(n)]  # [s][t]
-    true_root_blocks: list[TreeBlock] = []      # unpadded, for accounting
     with _obs_trace.span("planner.sample"):
         blks = _pmap(sample_exec,
                      lambda j: sample_tree_block(graph, j[2], num_layers,
@@ -314,8 +372,6 @@ def plan_iteration(graph: CSRGraph,
             pad_vertex[s] = loc[0] if loc.size else 0
         for (s, t, _, k), blk in zip(jobs, blks):
             blocks[s][t] = _pad_tree_block(blk, batch_pad, pad_vertex[s])
-            if k:
-                true_root_blocks.append(blk)
 
     # ---- gather plans ----
     def shard_needed(s: int, ts: Sequence[int]) -> np.ndarray:
@@ -415,28 +471,13 @@ def plan_iteration(graph: CSRGraph,
                 or [np.zeros(0, np.int64)]))
             for s in range(n)] if cache_index is not None else None)
 
-    # ---- accounting over true (unpadded) roots ----
+    # ---- accounting over true (unpadded) roots; the dedup-based figures
+    # are computed on first read (training reads none of them) ----
     with _obs_trace.span("planner.account"):
-        total_rows = sum(b.num_feature_rows() for b in true_root_blocks)
-        uniq_all: list[np.ndarray] = []
-        remote_nodedup = 0
-        step_unique = 0
-        for s in range(n):
-            per_step_ids = []
-            for t in range(T):
-                roots = amat.roots_at(s, t)
-                if roots.size == 0:
-                    continue
-                ids = blocks[s][t].select(np.arange(roots.size)).all_ids()
-                per_step_ids.append(ids)
-            if per_step_ids:
-                allids = np.concatenate(per_step_ids)
-                uniq_all.append(np.unique(allids))
-                for ids in per_step_ids:
-                    u = np.unique(ids)
-                    step_unique += u.size
-                    remote_nodedup += int((owner[u] != s).sum())
-        unique_rows = int(sum(u.size for u in uniq_all))
+        true_blocks = [(s, blk) for (s, _, _, k), blk in zip(jobs, blks)
+                       if k]
+        total_rows = sum(b.num_feature_rows() for _, b in true_blocks)
+        accounting = PlanAccounting(true_blocks, owner)
 
     return IterationPlan(
         num_shards=n, num_steps=T, fanout=fanout, num_layers=num_layers,
@@ -445,10 +486,8 @@ def plan_iteration(graph: CSRGraph,
         global_batch=int(sum(np.asarray(r).size for r in roots_per_model)),
         req=req, step_req=step_req, hop_idx=hop_idx, labels=lab_arr,
         weights=w_arr,
-        remote_rows_exact=remote_exact, remote_rows_nodedup=remote_nodedup,
-        total_rows=total_rows, unique_rows=unique_rows,
-        step_unique_rows=step_unique,
-        true_counts=counts, assignment=amat,
+        remote_rows_exact=remote_exact, total_rows=total_rows,
+        accounting=accounting, true_counts=counts, assignment=amat,
         c_max=c_max_eff,
         cache_version=(cache_index.version if cache_index is not None
                        else -1),

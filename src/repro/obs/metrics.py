@@ -20,6 +20,7 @@ Naming scheme: dotted ``subsystem.metric`` —
 - ``faults.*``     injected-fault firings, per kind
 - ``ckpt.*``       checkpoint saves/loads
 - ``epoch.*``      EpochStats published once per epoch
+- ``planner.*``    plans whose deferred accounting was computed
 
 Counters are monotonic (deltas are meaningful); gauges are last-write
 instantaneous values; histograms keep count/total/min/max (enough for
