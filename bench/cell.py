@@ -6,9 +6,12 @@
     bench/configs/<config>.json    the configuration as it is run
     bench/traffic/<traffic>.json   the traffic's parameters
     bench/metrics/<metric>.py      one per-layer metric each
+    bench/layers/<layer>.py        one GNN layer type each: its initial
+                                   weights, reference equations and
+                                   training FLOPs
 
-A new cell, configuration, traffic or metric is a new file; no existing
-file needs an edit.
+A new cell, configuration, traffic, metric or layer type is a new file;
+no existing file needs an edit.
 """
 from __future__ import annotations
 
@@ -18,6 +21,7 @@ from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+LAYERS = BENCH / "layers"
 
 
 def _load(kind: str, name: str) -> dict:
@@ -46,17 +50,26 @@ def per_layer_names(cell: str, spec: dict | None = None) -> list:
             if cell in m.get("workloads", [cell])]
 
 
-def load_metric(name: str):
-    """The reader module of per-layer metric ``name``."""
-    path = BENCH / "metrics" / f"{name}.py"
-    mod_name = "bench_metric_" + "".join(c if c.isalnum() else "_"
-                                         for c in name)
+def _module(path: Path, prefix: str, what: str):
+    mod_name = prefix + "".join(c if c.isalnum() else "_" for c in path.stem)
     spec = importlib.util.spec_from_file_location(mod_name, path)
     if spec is None or not path.is_file():
-        raise FileNotFoundError(f"no metric reader {path}")
+        raise FileNotFoundError(f"no {what} {path}")
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_metric(name: str):
+    """The reader module of per-layer metric ``name``."""
+    return _module(BENCH / "metrics" / f"{name}.py", "bench_metric_",
+                   "metric reader")
+
+
+def load_layer(name: str):
+    """The module of GNN layer type ``name`` (``init``, ``apply``,
+    ``train_flops``), from ``LAYERS/<name>.py``."""
+    return _module(LAYERS / f"{name}.py", "bench_layer_", "layer module")
 
 
 def peaks(device_kind: str) -> dict:

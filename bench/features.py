@@ -10,10 +10,13 @@ definition, never each other's copy.
 from __future__ import annotations
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import cell as cells
 
 
 def key_for(seed: int) -> jax.Array:
@@ -63,32 +66,23 @@ def glorot(key, shape):
 
 def init_params(seed: int, model: dict) -> dict:
     """Initial weights in the program's parameter layout
-    (``{"layers": [...], "head": {"w", "b"}}``), from ``seed``."""
-    return _init(key_for(seed), model["layer"], model["num_layers"],
-                 model["feature_dim"], model["hidden_dim"],
-                 model["classes"], model.get("heads", 1))
+    (``{"layers": [...], "head": {"w", "b"}}``), from ``seed``; each
+    layer's from ``init`` of its ``bench/layers/<layer>.py``."""
+    return _init(key_for(seed), json.dumps(model, sort_keys=True))
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6))
-def _init(key, layer, num_layers, feature_dim, hidden, classes, heads):
+@functools.partial(jax.jit, static_argnums=1)
+def _init(key, model_json: str):
+    model = json.loads(model_json)
+    layer = cells.load_layer(model["layer"])
+    num_layers, hidden = model["num_layers"], model["hidden_dim"]
     keys = jax.random.split(key, num_layers + 1)
     layers = []
-    d_in = feature_dim
+    d_in = model["feature_dim"]
     for i in range(num_layers):
-        k1, k2, k3 = jax.random.split(keys[i], 3)
-        if layer == "sage":
-            layers.append({"w_self": glorot(k1, (d_in, hidden)),
-                           "w_nbr": glorot(k2, (d_in, hidden)),
-                           "b": jnp.zeros((hidden,), jnp.float32)})
-        elif layer == "gat":
-            dh = hidden // heads
-            layers.append({
-                "w": glorot(k1, (d_in, hidden)),
-                "a_src": 0.1 * jax.random.normal(k2, (heads, dh)),
-                "a_dst": 0.1 * jax.random.normal(k3, (heads, dh))})
-        else:
-            raise ValueError(f"unknown layer {layer!r}")
+        layers.append(layer.init(jax.random.split(keys[i], 3), d_in, hidden,
+                                 model))
         d_in = hidden
-    head = {"w": glorot(keys[-1], (hidden, classes)),
-            "b": jnp.zeros((classes,), jnp.float32)}
+    head = {"w": glorot(keys[-1], (hidden, model["classes"])),
+            "b": jnp.zeros((model["classes"],), jnp.float32)}
     return {"layers": layers, "head": head}

@@ -8,24 +8,18 @@ sampler gives a vertex the same children wherever it recurs at one hop,
 so the tree layout's duplicate rows are recomputation and do not count.
 Per worker's mini-batch, with ``U[h]`` the distinct vertices at hop ``h``
 (``h = 0`` the roots, ``k`` layers, fanout ``f``), layer ``l`` (from the
-input) updates hops ``0 .. k-1-l`` from hops ``0 .. k-l``:
+input) updates hops ``0 .. k-1-l`` from hops ``0 .. k-l``; its count is
+``train_flops`` of the layer type's ``bench/layers/<layer>.py``. The head
+adds ``2 hidden classes`` per root.
 
-* GraphSAGE: two ``d_in x d_out`` matmuls per updated vertex, and the
-  mean over its ``f`` children (``f * d_in`` adds).
-* GAT: the projection of every vertex of hops ``0 .. k-l``, its
-  ``a_dst`` logit (``2 d_out``), the ``a_src`` logit of every updated
-  vertex (``2 d_out``) and its weighted sum over the self edge and ``f``
-  children (``(f + 1) 2 d_out``).
-* The head: ``2 hidden classes`` per root.
-
-Backward: matmuls and GAT's attention count three times the forward
-(gradients of weights and of inputs), except layer 0's matmuls, whose
-input is the feature table (twice: no input gradient); SAGE's means count
-twice, and not at all in backward in layer 0. Elementwise ops (biases,
-activations, softmax) are not counted.
+Backward: matmuls count three times the forward (gradients of weights and
+of inputs), except layer 0's, whose input is the feature table (twice: no
+input gradient); each layer file states the rest of its own. Elementwise
+ops (biases, activations, softmax) are not counted.
 """
 import numpy as np
 
+from bench import cell as cells
 from bench.reference import sample_tree
 
 LAYER = "step"
@@ -38,22 +32,12 @@ def train_flops(unique: list, model: dict) -> float:
     """Training FLOPs of one worker's message-flow graph with ``unique[h]``
     distinct vertices at hop ``h``."""
     k, f = int(model["num_layers"]), int(model["fanout"])
-    hidden, layer = int(model["hidden_dim"]), model["layer"]
+    hidden = int(model["hidden_dim"])
+    layer = cells.load_layer(model["layer"])
     total = 0.0
     d_in = int(model["feature_dim"])
     for l in range(k):
-        dst = sum(unique[h] for h in range(k - l))
-        mm_mult = 2.0 if l == 0 else 3.0
-        if layer == "sage":
-            total += mm_mult * dst * 2 * (2 * d_in * hidden)
-            total += (1.0 if l == 0 else 2.0) * dst * f * d_in
-        elif layer == "gat":
-            src = sum(unique[h] for h in range(k - l + 1))
-            total += mm_mult * src * 2 * d_in * hidden
-            total += 3.0 * (src * 2 * hidden
-                            + dst * (2 * hidden + (f + 1) * 2 * hidden))
-        else:
-            raise ValueError(f"no FLOP count for layer {layer!r}")
+        total += layer.train_flops(l, unique, k, f, d_in, hidden)
         d_in = hidden
     total += 3.0 * unique[0] * 2 * hidden * int(model["classes"])
     return total

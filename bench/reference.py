@@ -13,10 +13,10 @@ Semantics it reproduces, from the published description:
   the hash; a vertex without neighbours samples itself. The tree below a
   root is then a function of (root, seed) alone, so which worker trains a
   root at which time step cannot change it.
-* GraphSAGE-mean (``relu(h W_self + mean(children) W_nbr + b)``) or GAT
-  with a self edge, LeakyReLU(0.2) attention logits, softmax over the
-  self edge and the sampled children, ELU, heads concatenated; a linear
-  head; softmax cross-entropy averaged over all roots of the iteration.
+* The configuration's layer type, each layer's equations as
+  ``bench/layers/<layer>.py`` states them (``apply``), applied hop by hop
+  from the leaves up; a linear head; softmax cross-entropy averaged over
+  all roots of the iteration.
 * AdamW.
 
 It runs the model in float32 at ``"highest"`` matmul precision, in blocks
@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import cell as cells
 from bench.features import make_table
 
 BLOCK_ROOTS = 256
@@ -83,32 +84,11 @@ def _fp8(x):
     return x.astype(jnp.float8_e4m3fn).astype(jnp.float32)
 
 
-def _sage(p, parent, child, q):
-    return jax.nn.relu(q(parent) @ q(p["w_self"])
-                       + q(child.mean(axis=1)) @ q(p["w_nbr"]) + p["b"])
-
-
-def _gat(p, parent, child, q):
-    heads, dh = p["a_src"].shape
-    n, f, _ = child.shape
-    w = q(p["w"])
-    hp = (q(parent) @ w).reshape(n, heads, dh)
-    hc = (q(child) @ w).reshape(n, f, heads, dh)
-    vals = jnp.concatenate([hp[:, None], hc], axis=1)      # (n, f+1, h, dh)
-    src = jnp.einsum("nhd,hd->nh", q(hp), q(p["a_src"]))
-    dst = jnp.einsum("nfhd,hd->nfh", q(vals), q(p["a_dst"]))
-    alpha = jax.nn.softmax(jax.nn.leaky_relu(src[:, None] + dst, 0.2), axis=1)
-    return jax.nn.elu(jnp.einsum("nfh,nfhd->nhd", q(alpha), q(vals))
-                      .reshape(n, heads * dh))
-
-
-LAYERS = {"sage": _sage, "gat": _gat}
-
-
 def forward(params, layer: str, fanout: int, feats, q=lambda x: x):
-    """feats[h]: (B * fanout**h, d) -> logits (B, classes). ``q`` rounds
-    every matmul operand."""
-    apply = LAYERS[layer]
+    """feats[h]: (B * fanout**h, d) -> logits (B, classes). Each layer is
+    ``apply`` of ``bench/layers/<layer>.py``; ``q`` rounds every matmul
+    operand."""
+    apply = cells.load_layer(layer).apply
     hs = list(feats)
     for p in params["layers"]:
         hs = [apply(p, hs[h], hs[h + 1].reshape(hs[h].shape[0], fanout, -1),

@@ -17,19 +17,13 @@ TINY = {"num_layers": 2, "fanout": 2, "feature_dim": 3, "hidden_dim": 4,
         "classes": 5}
 
 
+LAYERS = sorted(p.stem for p in cells.LAYERS.glob("*.py"))
+
+
+# every layer file carries its hand count (HAND_COUNT) of TINY over 2, 3
+# and 4 distinct vertices at hops 0-2
 @pytest.mark.parametrize("layer, flops", [
-    # sage: layer 0 updates hops 0-1 (5 vertices): 2 matmuls of 3x4, fwd +
-    # weight grad (x2), means over 2 children of 3-d rows (fwd only);
-    # layer 1 updates hop 0 (2): 2 matmuls of 4x4 (x3), means of 4-d rows
-    # (x2); head 2 roots x 4x5 (x3)
-    ("sage", 2 * 5 * 2 * 24 + 5 * 2 * 3
-     + 3 * 2 * 2 * 32 + 2 * 2 * 2 * 4 + 3 * 2 * 2 * 20),
-    # gat: layer 0 projects hops 0-2 (9 vertices, 3x4, x2), attention over
-    # 9 sources and 5 targets with 2 children each (x3); layer 1 projects
-    # hops 0-1 (5, 4x4, x3), attention over 5 sources and 2 targets (x3)
-    ("gat", 2 * 9 * 24 + 3 * (9 * 8 + 5 * (8 + 3 * 8))
-     + 3 * 5 * 32 + 3 * (5 * 8 + 2 * (8 + 3 * 8)) + 3 * 2 * 2 * 20),
-])
+    (name, cells.load_layer(name).HAND_COUNT) for name in LAYERS])
 def test_bench_train_flops_hand_count(layer, flops):
     assert MFU.train_flops([2, 3, 4], dict(TINY, layer=layer)) == flops
 

@@ -1,5 +1,6 @@
-"""The benchmark's data files: every cell, configuration, traffic and
-metric that BENCHMARK.json names exists, parses, and agrees with it."""
+"""The benchmark's data files: every cell, configuration, traffic,
+metric and layer type that BENCHMARK.json names exists, parses, and
+agrees with it."""
 import json
 import sys
 from pathlib import Path
@@ -56,6 +57,21 @@ def test_bench_metric_readers(m):
     assert mod.UNIT == m["unit"]
     assert set(m["workloads"]) <= set(CELLS)
     assert callable(mod.read)
+
+
+@pytest.mark.parametrize("entry", SPEC["configs"], ids=lambda e: e["name"])
+def test_bench_config_layer_has_a_file(entry):
+    cfg = json.loads((ROOT / entry["file"]).read_text())
+    assert (cells.LAYERS / f"{cfg['model']['layer']}.py").is_file()
+
+
+@pytest.mark.parametrize("path", sorted(cells.LAYERS.glob("*.py")),
+                         ids=lambda p: p.stem)
+def test_bench_layer_module_exposes_its_functions(path):
+    mod = cells.load_layer(path.stem)
+    assert all(callable(getattr(mod, f, None))
+               for f in ("init", "apply", "train_flops"))
+    assert isinstance(mod.HAND_COUNT, int) and mod.HAND_COUNT > 0
 
 
 def test_bench_spec_shape():
