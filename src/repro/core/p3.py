@@ -13,10 +13,14 @@ This module executes that schedule. Because a dim-sliced matmul summed over
 slices equals the full matmul, P³'s gradients match model-centric training
 to float tolerance — verified in tests (the same kind of placement-only
 equivalence LeapGNN has). Supported models: gcn, sage, gat (input layer is
-matmul-fronted; deepgcn/film normalize *pre-matmul* over the full feature
-vector, which P³'s slicing cannot express without an extra all-gather —
-the paper's own "P³ suits particular architectures" caveat, surfaced as
-``P3Unsupported``).
+matmul-fronted). deepgcn normalizes *pre-matmul* over the full feature
+vector, which P³'s slicing cannot express without an extra all-gather.
+film multiplies two matmul outputs of the input layer (the FiLM scale
+``h G`` times each message ``c W``), so every one of its four input
+matmuls, the per-edge one included, would need its own partial-sum
+reduction before the product; this module implements no such split.
+Both are the paper's own "P³ suits particular architectures" caveat,
+surfaced as ``P3Unsupported``.
 
 Comm accounting mirrors core.comm_model.p3_bytes: hidden activations of
 hops 0..k-1 cross the fabric (pull + gradient push), raw features never do.

@@ -12,7 +12,10 @@ trace can be read by program region instead of by op family:
   only the Pallas call, and the rest of ``gather`` is the row relayout
   around it (pad to whole lane tiles, reshape, slice back);
 - ``layers``   — the model's loss, forward and (through autodiff)
-  backward;
+  backward; inside it, GNN-FiLM's layers name their two parts:
+  ``film.edges`` (the per-edge transform, modulation, activation and
+  mean over the children) and ``film.nodes`` (both hypernetworks, the
+  self message and the LayerNorm);
 - ``update``   — the gradient reduction and the optimizer update.
 
 Scopes are metadata only: they change no numerics and cost nothing at
@@ -22,4 +25,6 @@ EXCHANGE = "exchange"
 GATHER = "gather"
 KERNEL = "kernel"
 LAYERS = "layers"
+FILM_EDGES = "film.edges"
+FILM_NODES = "film.nodes"
 UPDATE = "update"

@@ -111,7 +111,7 @@ def test_lo_zero_remote(partitioned):
 # Gradient parity (Table 3 as a theorem, not a statistic)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("model", ["gcn", "sage", "gat"])
+@pytest.mark.parametrize("model", ["gcn", "sage", "gat", "film"])
 def test_gradient_parity_hopgnn_vs_model_centric(partitioned, model):
     d = partitioned
     cfg = GNNConfig(model=model, num_layers=2, hidden_dim=32,

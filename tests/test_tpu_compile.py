@@ -10,6 +10,8 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, so under several test
 workers only the worker running this file loads it.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -70,23 +72,31 @@ def test_ops_dispatch_picks_kernel_for_tpu(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_fused_train_step_holds_kernel(one_chip):
+# the FiLM step emulates two shards, not four: its 11-hop program takes
+# half as long to compile, and still holds one kernel per (shard, hop)
+@pytest.mark.parametrize("model, layers, fanout, n",
+                         [("sage", 2, 10, 4), ("film", 10, 2, 2)],
+                         ids=["sage", "film"])
+def test_fused_train_step_holds_kernel(one_chip, model, layers, fanout, n):
     """The fused train step (emulated shards, pregather) compiled for the
-    TPU gathers every hop with the Pallas kernel."""
+    TPU gathers every hop with the Pallas kernel: one call per (shard,
+    hop), also over the 11 hops of the 10-layer FiLM tree, whose layers'
+    ops carry their ``film.edges`` and ``film.nodes`` scopes."""
     from repro.core import distributed as engine
     from repro.models.gnn import GNNConfig
     from repro.models.gnn.models import init_gnn
+    from repro.obs.scopes import FILM_EDGES, FILM_NODES
     from repro.optim import adamw
-    cfg = GNNConfig(model="sage", num_layers=2, hidden_dim=128,
-                    feature_dim=100, num_classes=47, fanout=10)
+    cfg = GNNConfig(model=model, num_layers=layers, hidden_dim=128,
+                    feature_dim=100, num_classes=47, fanout=fanout)
     opt = adamw(1e-3)
-    n, t, bp, r_max = 4, 2, 16, 512
+    t, bp, r_max = 2, 16, 512
     params = jax.eval_shape(lambda: init_gnn(jax.random.PRNGKey(0), cfg))
 
     def on_chip(tree):
         return jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), tree)
     dev = dict(req=_sds((n, n, r_max), jnp.int32, one_chip), step_req=None,
-               hop_idx=[_sds((n, t, bp * 10 ** h), jnp.int32, one_chip)
+               hop_idx=[_sds((n, t, bp * fanout ** h), jnp.int32, one_chip)
                         for h in range(cfg.num_layers + 1)],
                labels=_sds((n, t, bp), jnp.int32, one_chip),
                weights=_sds((n, t, bp), jnp.float32, one_chip))
@@ -96,4 +106,8 @@ def test_fused_train_step_holds_kernel(one_chip):
                     _sds((n, 0, 100), jnp.float32, one_chip), dev,
                     _sds((), jnp.float32, one_chip)).compile().as_text()
     # one kernel per (shard, hop)
-    assert text.count("tpu_custom_call") >= n * (cfg.num_layers + 1)
+    assert text.count("tpu_custom_call") == n * (cfg.num_layers + 1)
+    if model == "film":
+        names = re.findall(r'op_name="([^"]*)"', text)
+        for scope in (FILM_EDGES, FILM_NODES):
+            assert any(f"/{scope}/" in name for name in names), scope
