@@ -21,7 +21,9 @@ Remote-feature cache (repro.cache): every iteration body takes a
 ``(N, c_max, d)`` cache table next to the feature table; the per-shard
 workspace is assembled as ``[local | cached | fetched]`` rows, matching the
 planner's slot layout. ``c_max = 0`` (the default when no cache is passed)
-degenerates to the original two-region workspace.
+degenerates to the original two-region workspace. Each workspace is laid
+out in the gather kernel's word layout where it is assembled, once, and
+every hop and time step gathers from those words (:func:`_workspace`).
 
 Per-step collectives: the T index requests ship in ONE batched all_to_all
 hoisted ahead of the time-step scan (PR 2). When ``T·r_max`` fits
@@ -223,14 +225,25 @@ class EmulatedComm:
 # Per-shard iteration body (comm-free inner compute)
 # ---------------------------------------------------------------------------
 
+def _workspace(parts):
+    """A shard's feature workspace ``[local | cached | fetched]`` (the
+    planner's slot layout), laid out once in the gather kernel's word
+    layout (``kernels.ops.Words``): every hop of every time step that
+    reads it gathers the words, and no relayout of the whole workspace
+    runs per gather. Call it inside the ``exchange`` scope."""
+    from repro.kernels import ops
+    return ops.as_words(jnp.concatenate(parts, 0))
+
+
 def _shard_grads(params, cfg: GNNConfig, workspace_fn: Callable,
                  hop_idx, labels, weights):
     """Scan the time steps of one shard, accumulating grads and loss.
 
-    workspace_fn(t) -> (rows, d) feature workspace for step t (constant
-    across steps in pregather mode). The per-hop feature gather is the
-    Pallas ``gather_rows`` kernel on TPU (kernels/gather_agg.py) and
-    ``jnp.take`` on CPU — dispatched by kernels.ops."""
+    workspace_fn(t) -> the step's feature workspace from :func:`_workspace`
+    (the same one for every step in pregather mode, made before the scan).
+    The per-hop feature gather, ``kernels.ops.gather_rows``, reads its
+    words: the Pallas kernel on TPU (kernels/gather_agg.py), ``jnp.take``
+    of the same words on CPU."""
     from repro.kernels import ops
     T = labels.shape[0]
 
@@ -263,10 +276,9 @@ def _iteration_shard(params, table, cache, dev, cfg: GNNConfig,
     traced scalar (not static — see module doc)."""
     d = table.shape[1]
     with jax.named_scope(EXCHANGE):
-        base = jnp.concatenate([table, cache], 0)     # [local | cached]
         if pregather:
             recv = comm.exchange(table, dev["req"])        # (P, r_max, d)
-            ws = jnp.concatenate([base, recv.reshape(-1, d)], 0)
+            ws = _workspace([table, cache, recv.reshape(-1, d)])
             workspace_fn = lambda t: ws
         else:
             # All T index requests ship in one batched all_to_all before
@@ -282,13 +294,13 @@ def _iteration_shard(params, table, cache, dev, cfg: GNNConfig,
                 # workspace_fn runs in the scan body, outside this block
                 @jax.named_scope(EXCHANGE)
                 def workspace_fn(t):
-                    return jnp.concatenate(
-                        [base, recv_all[t].reshape(-1, d)], 0)
+                    return _workspace([table, cache,
+                                       recv_all[t].reshape(-1, d)])
             else:
                 @jax.named_scope(EXCHANGE)
                 def workspace_fn(t):
                     recv = comm.serve_features(table, incoming[t])
-                    return jnp.concatenate([base, recv.reshape(-1, d)], 0)
+                    return _workspace([table, cache, recv.reshape(-1, d)])
     grads, loss_sum = _shard_grads(params, cfg, workspace_fn,
                                    dev["hop_idx"], dev["labels"], dev["weights"])
     with jax.named_scope(UPDATE):
@@ -403,7 +415,8 @@ def get_compiled_inference(cfg: GNNConfig):
         def infer(params, cache_tab, fetched, *hop_idx):
             _note_trace("infer", cfg, True, fetched, cache_tab,
                         list(hop_idx))
-            ws = jnp.concatenate([cache_tab, fetched], 0)
+            with jax.named_scope(EXCHANGE):
+                ws = _workspace([cache_tab, fetched])
             feats = [ops.gather_rows(ws, i) for i in hop_idx]
             return gnn_forward(params, cfg, feats)
 
@@ -732,8 +745,8 @@ def _streamed_shard(params, cache, dev, cfg: GNNConfig, denom,
     so no feature collective runs; only the gradient psum remains."""
     d = dev["feat_local"].shape[-1]
     with jax.named_scope(EXCHANGE):
-        ws = jnp.concatenate([dev["feat_local"], cache,
-                              dev["feat_fetch"].reshape(-1, d)], 0)
+        ws = _workspace([dev["feat_local"], cache,
+                         dev["feat_fetch"].reshape(-1, d)])
     grads, loss_sum = _shard_grads(params, cfg, lambda t: ws,
                                    dev["hop_idx"], dev["labels"],
                                    dev["weights"])
@@ -845,8 +858,8 @@ def _emulated_streamed_iteration(params, cache_g, dev, denom,
     per_shard = []
     for s in range(n):
         with jax.named_scope(EXCHANGE):
-            ws = jnp.concatenate([dev["feat_local"][s], cache_g[s],
-                                  dev["feat_fetch"][s].reshape(-1, d)], 0)
+            ws = _workspace([dev["feat_local"][s], cache_g[s],
+                             dev["feat_fetch"][s].reshape(-1, d)])
         hop_idx = [h[s] for h in dev["hop_idx"]]
         g, l = _shard_grads(params, cfg, lambda t, ws=ws: ws, hop_idx,
                             dev["labels"][s], dev["weights"][s])
@@ -879,23 +892,21 @@ def _emulated_iteration(params, table_g, cache_g, dev, denom, cfg: GNNConfig,
                     table_g, incoming_g)
     per_shard = []
     for s in range(n):
-        with jax.named_scope(EXCHANGE):
-            # [local | cached], then the fetched rows
-            base = jnp.concatenate([table_g[s], cache_g[s]], 0)
-            if pregather:
-                ws = jnp.concatenate([base, recv_g[s].reshape(-1, d)], 0)
-                workspace_fn = lambda t, ws=ws: ws
-            elif fold_returns:
-                # workspace_fn runs in the scan body, outside this block
-                @jax.named_scope(EXCHANGE)
-                def workspace_fn(t, s=s, base=base):
-                    return jnp.concatenate(
-                        [base, recv_all_g[s, t].reshape(-1, d)], 0)
-            else:
-                @jax.named_scope(EXCHANGE)
-                def workspace_fn(t, s=s, base=base):
-                    recv = ecomm.serve_step_global(table_g, incoming_g, t, s)
-                    return jnp.concatenate([base, recv.reshape(-1, d)], 0)
+        base = [table_g[s], cache_g[s]]                 # [local | cached]
+        if pregather:
+            with jax.named_scope(EXCHANGE):
+                ws = _workspace(base + [recv_g[s].reshape(-1, d)])
+            workspace_fn = lambda t, ws=ws: ws
+        elif fold_returns:
+            # workspace_fn runs in the scan body
+            @jax.named_scope(EXCHANGE)
+            def workspace_fn(t, s=s, base=base):
+                return _workspace(base + [recv_all_g[s, t].reshape(-1, d)])
+        else:
+            @jax.named_scope(EXCHANGE)
+            def workspace_fn(t, s=s, base=base):
+                recv = ecomm.serve_step_global(table_g, incoming_g, t, s)
+                return _workspace(base + [recv.reshape(-1, d)])
         hop_idx = [h[s] for h in dev["hop_idx"]]
         g, l = _shard_grads(params, cfg, workspace_fn, hop_idx,
                             dev["labels"][s], dev["weights"][s])

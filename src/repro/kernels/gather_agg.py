@@ -2,8 +2,10 @@
 
 These are the compute hot-spots of LeapGNN's data path (DESIGN.md §2):
 
-* ``gather_rows``  — workspace row gather ``out[i] = table[idx[i]]``; the
-  inner op of pre-gathering (§5.2) and of every tree-block feature load.
+* ``gather_words`` — workspace row gather ``out[i] = table[idx[i]]`` from a
+  workspace already laid out by :func:`as_words`; the inner op of
+  pre-gathering (§5.2) and of every tree-block feature load.
+  ``gather_rows`` lays a plain ``(R, d)`` table out and gathers from it.
 * ``gather_agg``   — fused neighbor gather + segment reduction over the
   fixed-fanout axis, replacing DGL's SpMM. On GPU this is a scatter-based
   sparse kernel; the TPU-native re-expression uses the *regular* (n, f)
@@ -11,15 +13,19 @@ These are the compute hot-spots of LeapGNN's data path (DESIGN.md §2):
   output block resident in VMEM — no atomics (TPU has none), no scatter.
 
 Both kernels move rows with explicit DMAs. The table stays in HBM
-(``memory_space=pl.ANY``) viewed as ``(R, 1, w)`` rows of ``w`` 32-bit
+(``memory_space=pl.ANY``) laid out as ``(R, 1, w)`` rows of ``w`` 32-bit
 words, ``w`` a multiple of the 128-lane tile: Mosaic refuses a DMA of one
 row of an ``(R, d)`` array (a one-sublane slice of an (8, 128) tile) and
 of a row narrower than a lane tile, but copies a whole ``(1, w)`` row of
-the 3-D view. Each grid step takes :data:`ROWS_PER_STEP` indices as an
-SMEM block, starts one row DMA per index into its VMEM output block, and
-waits for all of them; the output block is then written back by the
-pipeline. Index blocks are per step, so the index count is not bounded by
-SMEM (1 MiB on v5e), as it is for a scalar-prefetched index array.
+the 3-D view. That layout is a padded copy of the whole table, so the
+gather takes the workspace already laid out: the caller makes the words
+once where it assembles the workspace (``core.distributed``) and gathers
+every hop and time step from them. Each grid step takes
+:data:`ROWS_PER_STEP` indices as an SMEM block, starts one row DMA per
+index into its VMEM output block, and waits for all of them; the output
+block is then written back by the pipeline. Index blocks are per step,
+so the index count is not bounded by SMEM (1 MiB on v5e), as it is for a
+scalar-prefetched index array.
 """
 from __future__ import annotations
 
@@ -39,7 +45,7 @@ LANE = 128  # TPU lane width; rows are padded to whole lane tiles
 ROWS_PER_STEP = 1024
 
 
-def _as_words(table: jnp.ndarray) -> jnp.ndarray:
+def as_words(table: jnp.ndarray) -> jnp.ndarray:
     """(R, d) table -> (R, 1, w) rows of 32-bit words, w % LANE == 0.
     Rows of 8-/16-bit dtypes are packed into uint32 words."""
     R, d = table.shape
@@ -53,7 +59,7 @@ def _as_words(table: jnp.ndarray) -> jnp.ndarray:
 
 
 def _from_words(words: jnp.ndarray, n: int, dtype, d: int) -> jnp.ndarray:
-    """Inverse of :func:`_as_words` for the first ``n`` gathered rows."""
+    """Inverse of :func:`as_words` for the first ``n`` gathered rows."""
     x = words[:n, 0]
     if x.dtype != dtype:
         x = jax.lax.bitcast_convert_type(x, dtype).reshape(n, -1)
@@ -95,13 +101,12 @@ def _gather_rows_kernel(idx_ref, table_hbm, out_ref, sem, *, n: int):
     _dma_rows(idx_ref, table_hbm, out_ref, sem, count)
 
 
-def gather_rows(table: jnp.ndarray, idx: jnp.ndarray,
-                interpret: bool = False) -> jnp.ndarray:
-    """table: (R, d), idx: (n,) int32 -> (n, d). The Pallas call runs
-    under the ``kernel`` scope; the relayout around it does not."""
-    n, d = idx.shape[0], table.shape[1]
-    words = _as_words(table)
-    w = words.shape[-1]
+def gather_words(words: jnp.ndarray, idx: jnp.ndarray, dtype, d: int,
+                 interpret: bool = False) -> jnp.ndarray:
+    """words: (R, 1, w) from :func:`as_words` of an (R, d) table of
+    ``dtype``; idx: (n,) int32 -> (n, d). The Pallas call runs under the
+    ``kernel`` scope; the unpacking of its output does not."""
+    n, w = idx.shape[0], words.shape[-1]
     idx_p = _padded(idx)
     with jax.named_scope(KERNEL):
         out = pl.pallas_call(
@@ -117,7 +122,15 @@ def gather_rows(table: jnp.ndarray, idx: jnp.ndarray,
                                            words.dtype),
             interpret=interpret,
         )(idx_p, words)
-    return _from_words(out, n, table.dtype, d)
+    return _from_words(out, n, dtype, d)
+
+
+def gather_rows(table: jnp.ndarray, idx: jnp.ndarray,
+                interpret: bool = False) -> jnp.ndarray:
+    """table: (R, d), idx: (n,) int32 -> (n, d): :func:`gather_words`
+    from the table laid out for this one call."""
+    return gather_words(as_words(table), idx, table.dtype, table.shape[1],
+                        interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -160,7 +173,7 @@ def gather_agg(table: jnp.ndarray, idx: jnp.ndarray, reduce: str = "sum",
     """
     n, f = idx.shape
     d = table.shape[1]
-    words = _as_words(table.astype(jnp.float32))
+    words = as_words(table.astype(jnp.float32))
     w = words.shape[-1]
     idx_p = _padded(idx.T).reshape(-1)                 # (f * n_pad,)
     blocks = idx_p.shape[0] // f // ROWS_PER_STEP

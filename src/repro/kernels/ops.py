@@ -6,8 +6,13 @@ not by the process's default backend, so a step compiled for a TPU always
 holds the Pallas kernel —
 
   * TPU      → the Pallas kernel (compiled),
-  * CPU/test → the pure-jnp oracle (ref.py), or the kernel in interpret
-               mode when ``force_kernel=True`` (how tests exercise it).
+  * CPU/test → pure jnp, or the kernel in interpret mode when
+               ``force_kernel=True`` (how tests exercise it).
+
+The row gather reads a workspace laid out once in the kernel's word
+layout (:class:`Words`, made by :func:`as_words`); its CPU branch takes
+the same words with ``jnp.take``, so tests and the chip run one layout.
+The fused gather+aggregate's CPU branch is its ref.py oracle.
 
 Training on the TPU gathers with the kernel: the gathered table holds
 input features, which are never differentiated, so the kernel needs no
@@ -16,6 +21,7 @@ its Pallas kernel serves prefill on the TPU.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -24,6 +30,7 @@ import jax.numpy as jnp
 from repro.kernels import gather_agg as _ga
 from repro.kernels import linattn as _la
 from repro.kernels import ref as _ref
+from repro.obs import metrics as _obs_metrics
 from repro.obs.scopes import GATHER
 
 
@@ -31,13 +38,39 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def gather_rows(table: jnp.ndarray, idx: jnp.ndarray,
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["words"], meta_fields=["dtype", "d"])
+@dataclasses.dataclass(frozen=True)
+class Words:
+    """A gather workspace in the kernel's word layout: ``words`` is
+    :func:`gather_agg.as_words` of an ``(R, d)`` table of ``dtype``."""
+    words: jax.Array
+    dtype: jnp.dtype
+    d: int
+
+
+def as_words(table: jnp.ndarray) -> Words:
+    """Lay an (R, d) workspace out once for every :func:`gather_rows` from
+    it. Counted at trace time as ``gather.workspace_layouts``."""
+    _obs_metrics.inc("gather.workspace_layouts")
+    return Words(_ga.as_words(table), jnp.dtype(table.dtype), table.shape[1])
+
+
+def _take_words(words, idx, dtype, d):
+    return _ga._from_words(jnp.take(words, idx, axis=0), idx.shape[0],
+                           dtype, d)
+
+
+def gather_rows(ws: Words, idx: jnp.ndarray,
                 force_kernel: bool = False) -> jnp.ndarray:
-    """out[i] = table[idx[i]], under the ``gather`` scope."""
-    other = (functools.partial(_ga.gather_rows, interpret=True)
-             if force_kernel else _ref.gather_rows_ref)
+    """out[i] = row idx[i] of the workspace ``ws``, under the ``gather``
+    scope. Both branches gather the same words and unpack them the same
+    way."""
     with jax.named_scope(GATHER):
-        return jax.lax.platform_dependent(table, idx, tpu=_ga.gather_rows,
+        tpu = functools.partial(_ga.gather_words, dtype=ws.dtype, d=ws.d)
+        other = (functools.partial(tpu, interpret=True) if force_kernel
+                 else functools.partial(_take_words, dtype=ws.dtype, d=ws.d))
+        return jax.lax.platform_dependent(ws.words, idx, tpu=tpu,
                                           default=other)
 
 

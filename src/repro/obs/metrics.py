@@ -21,6 +21,7 @@ Naming scheme: dotted ``subsystem.metric`` —
 - ``ckpt.*``       checkpoint saves/loads
 - ``epoch.*``      EpochStats published once per epoch
 - ``planner.*``    plans whose deferred accounting was computed
+- ``gather.*``     gather workspaces laid out in the kernel's words (per trace)
 
 Counters are monotonic (deltas are meaningful); gauges are last-write
 instantaneous values; histograms keep count/total/min/max (enough for

@@ -7,10 +7,11 @@ trace can be read by program region instead of by op family:
 
 - ``exchange`` — the §5.2 feature exchange: the emulated ``jnp.take``s,
   the ``ShardComm`` all-to-alls, and the ``[local | cached | fetched]``
-  workspace concatenation;
+  workspace concatenation with its one layout in the gather kernel's
+  words (pad to whole lane tiles, reshape);
 - ``gather``   — ``kernels.ops.gather_rows``; inside it, ``kernel`` wraps
-  only the Pallas call, and the rest of ``gather`` is the row relayout
-  around it (pad to whole lane tiles, reshape, slice back);
+  only the Pallas call, and the rest of ``gather`` is the work around it
+  (the index pad, the slice of the output back to the feature width);
 - ``layers``   — the model's loss, forward and (through autodiff)
   backward; inside it, GNN-FiLM's layers name their two parts:
   ``film.edges`` (the per-edge transform, modulation, activation and

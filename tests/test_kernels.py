@@ -9,13 +9,14 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops
-from repro.kernels.gather_agg import gather_agg, gather_rows
+from repro.kernels.gather_agg import gather_agg, gather_rows, gather_words
 from repro.kernels.linattn import linattn_chunked
 from repro.kernels.ref import gather_agg_ref, gather_rows_ref, linattn_ref
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("rows,d", [(16, 128), (64, 256), (33, 96)])
+@pytest.mark.parametrize("rows,d", [(16, 128), (64, 256), (33, 96),
+                                    (40, 100), (24, 600)])
 def test_gather_rows_sweep(rows, d, dtype):
     rng = np.random.default_rng(0)
     table = jnp.asarray(rng.standard_normal((rows, d)), dtype)
@@ -46,13 +47,13 @@ def test_dim_splits_lane_tiling(dtype):
     """The kernels DMA whole rows of 32-bit words padded to lane tiles:
     every width maps to a (R, 1, w) view with w % LANE == 0, 16-bit rows
     pack two values per word, and the view round-trips bit-exactly."""
-    from repro.kernels.gather_agg import LANE, _as_words, _from_words
+    from repro.kernels.gather_agg import LANE, _from_words, as_words
     per_word = 4 // jnp.dtype(dtype).itemsize
     rng = np.random.default_rng(8)
     for d, w in [(128, 128), (256, 256), (96, 128), (192, 256),
                  (300, 384), (600, 640)]:
         table = jnp.asarray(rng.standard_normal((5, d)), dtype)
-        words = _as_words(table)
+        words = as_words(table)
         assert words.shape == (5, 1, -(-w // per_word // LANE) * LANE)
         np.testing.assert_array_equal(
             np.asarray(_from_words(words, 5, table.dtype, d)),
@@ -70,7 +71,7 @@ def test_gather_rows_d192_lane_split(dtype):
                                   np.asarray(gather_rows_ref(table, idx)))
 
 
-@pytest.mark.parametrize("op", ["rows", "agg"])
+@pytest.mark.parametrize("op", ["rows", "agg", "words"])
 def test_gather_spans_grid_steps(op):
     """More indices than one grid step DMAs, ending in a partial block:
     every block gathers its own rows and the tail stops at n."""
@@ -81,6 +82,12 @@ def test_gather_spans_grid_steps(op):
     if op == "rows":
         idx = jnp.asarray(rng.integers(0, 300, n), jnp.int32)
         out, ref = (gather_rows(table, idx, interpret=True),
+                    gather_rows_ref(table, idx))
+    elif op == "words":
+        idx = jnp.asarray(rng.integers(0, 300, n), jnp.int32)
+        ws = ops.as_words(table)
+        out, ref = (gather_words(ws.words, idx, ws.dtype, ws.d,
+                                 interpret=True),
                     gather_rows_ref(table, idx))
     else:
         idx = jnp.asarray(rng.integers(0, 300, (n, 3)), jnp.int32)
@@ -199,6 +206,58 @@ def test_linattn_property_random_shapes(bh, chunks_, dk_pow, seed):
                                rtol=2e-3, atol=2e-3)
     np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref),
                                rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("path", ["interpret", "ops", "ops-kernel"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("d", [100, 128, 600])
+def test_gather_from_workspace_in_words(d, dtype, path):
+    """A workspace assembled in words by the engine's helper, gathered by
+    the interpret-mode kernel, by the ops CPU branch (``jnp.take`` of the
+    same words) and by ops forcing the kernel, equals ``jnp.take`` on the
+    concatenated (R, d) workspace bit for bit."""
+    from repro.core.distributed import _workspace
+    rng = np.random.default_rng(d)
+    parts = [jnp.asarray(rng.standard_normal((r, d)), dtype)
+             for r in (37, 0, 3 * 11)]            # [local | cached | fetched]
+    idx = jnp.asarray(rng.integers(0, 70, 53), jnp.int32)
+    ws = _workspace(parts)
+    assert (ws.dtype, ws.d, ws.words.shape[0]) == (jnp.dtype(dtype), d, 70)
+    if path == "interpret":
+        out = gather_words(ws.words, idx, ws.dtype, ws.d, interpret=True)
+    else:
+        out = ops.gather_rows(ws, idx, force_kernel=path == "ops-kernel")
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(jnp.take(jnp.concatenate(parts), idx,
+                                             axis=0)))
+
+
+def test_workspace_laid_out_once_per_shard(partitioned):
+    """Tracing one emulated pregather step lays out one workspace per
+    shard, not one per (shard, time step, hop)."""
+    from repro.core import distributed as engine
+    from repro.core import plan_iteration
+    from repro.models.gnn import GNNConfig, init_gnn
+    from repro.obs import metrics
+    d = partitioned
+    rng = np.random.default_rng(0)
+    tv = d["ds"].train_vertices()
+    roots = [rng.choice(tv, 12, replace=False) for _ in range(d["parts"])]
+    plan = plan_iteration(d["ds"].graph, d["ds"].labels, d["part"],
+                          d["owner"], d["local_idx"], d["table"].shape[1],
+                          roots, num_layers=2, fanout=4, sample_seed=7,
+                          pregather=True)
+    assert plan.num_steps > 1
+    cfg = GNNConfig(model="sage", num_layers=2, hidden_dim=16,
+                    feature_dim=d["ds"].feature_dim,
+                    num_classes=d["ds"].num_classes, fanout=4)
+    params = init_gnn(jax.random.PRNGKey(0), cfg)
+    fn = engine._build_emulated(cfg, True, False)
+    args = engine.prepare_iteration_args(d["table"], plan)
+    counter = metrics.registry().counter("gather.workspace_layouts")
+    before = counter.value
+    fn.lower(params, *args)
+    assert counter.value - before == d["parts"]
 
 
 def test_gather_rows_used_by_engine(partitioned):
